@@ -11,9 +11,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as PS
-
-from repro.core.compat import shard_map
 
 
 def _check_divisible(fn: str, what: str, dim: int, by: int, why: str):

@@ -42,10 +42,10 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 import numpy as np
 
 from repro.core import isa
-from repro.core.compat import shard_map as _shard_map
 from repro.core.precision import SEW_TO_DTYPE
 
 NF_MAX = max(isa.LMULS)          # nf * lmul <= 8 caps fields at 8

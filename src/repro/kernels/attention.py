@@ -72,14 +72,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref, probe_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        probe_ref[0, 0] = 0
+        probe_ref[...] = jnp.zeros_like(probe_ref)
 
     def _work():
         q = q_ref[0]                       # (bq, d)
         k = k_ref[0]                       # (bk, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = (kvm_ref[0] != 0)[None, :]
+        mask = kvm_ref[0] != 0             # (1, bk)
         if causal:
             q_pos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -99,7 +99,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref, probe_ref,
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
         m_ref[...] = m_new
-        probe_ref[0, 0] += 1
+        probe_ref[...] += 1
 
     if causal:
         pl.when(_causal_need(qb, kb, bq, bk))(_work)
@@ -115,15 +115,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref, probe_ref,
         l_safe = jnp.where(live, l, 1.0)
         o_ref[0] = jnp.where(live, acc_ref[...] / l_safe, 0.0) \
             .astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(live[:, 0],
-                               m_ref[...][:, 0] + jnp.log(l_safe[:, 0]),
-                               NEG_INF)
+        lse_ref[0] = jnp.where(live, m_ref[...] + jnp.log(l_safe), NEG_INF)
 
 
 def _fwd_call(qf, kf, vf, kvm, *, causal: bool, bq: int, bk: int,
               interpret: bool):
-    """Padded flat call: qf (G,Sq,D), kf/vf (G,Sk,D), kvm (G,Sk) int32.
-    Returns (out (G,Sq,D), lse (G,Sq) f32, probe (G,n_q) int32)."""
+    """Padded flat call: qf (G,Sq,D), kf/vf (G,Sk,D), kvm (G,1,Sk) int32.
+    Returns (out (G,Sq,D), lse (G,Sq,1) f32, probe (G,n_q,1,1) int32).
+
+    Every block's last two dims are full array dims or (8, 128)-aligned,
+    as the TPU lowering requires: the key mask is a row per group
+    (block (1,1,bk)), the per-row stats are columns (block (1,bq,1), the
+    same (bq, 1) shape as the m/l scratch), and the probe carries two unit
+    trailing dims so each (group, q-block) counter is a whole block."""
     g, sq, d = qf.shape
     sk = kf.shape[1]
     n_q, n_k = sq // bq, sk // bk
@@ -136,17 +140,17 @@ def _fwd_call(qf, kf, vf, kvm, *, causal: bool, bq: int, bk: int,
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
             pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kb, 0)),
             pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kb, 0)),
-            pl.BlockSpec((1, bk), lambda gi, qb, kb: (gi, kb)),
+            pl.BlockSpec((1, 1, bk), lambda gi, qb, kb: (gi, 0, kb)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
-            pl.BlockSpec((1, bq), lambda gi, qb, kb: (gi, qb)),
-            pl.BlockSpec((1, 1), lambda gi, qb, kb: (gi, qb)),
+            pl.BlockSpec((1, bq, 1), lambda gi, qb, kb: (gi, qb, 0)),
+            pl.BlockSpec((1, 1, 1, 1), lambda gi, qb, kb: (gi, qb, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, sq, d), qf.dtype),
-            jax.ShapeDtypeStruct((g, sq), jnp.float32),
-            jax.ShapeDtypeStruct((g, n_q), jnp.int32),
+            jax.ShapeDtypeStruct((g, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((g, n_q, 1, 1), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -168,13 +172,13 @@ def _recompute_p(q_ref, k_ref, kvm_ref, lse_ref, qb, kb, *,
     Masked positions and dead rows come back exactly 0."""
     s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    mask = (kvm_ref[0] != 0)[None, :]
+    mask = kvm_ref[0] != 0                 # (1, bk)
     if causal:
         q_pos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         mask = mask & (q_pos >= k_pos)
-    lse = lse_ref[0]
-    lse_safe = jnp.where(lse > _DEAD_ROW, lse, 0.0)[:, None]
+    lse = lse_ref[0]                       # (bq, 1)
+    lse_safe = jnp.where(lse > _DEAD_ROW, lse, 0.0)
     return jnp.where(mask, jnp.exp(s - lse_safe), 0.0)
 
 
@@ -194,7 +198,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0]
         dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dq_acc[...] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -229,7 +233,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -271,7 +275,7 @@ def _flash_core_bwd(causal, bq, bk, interpret, res, dout):
     scale = 1.0 / math.sqrt(d)
     # D_i = sum_j dO_ij * O_ij, shared by both backward kernels
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)
+                    axis=-1, keepdims=True)
     common = dict(scale=scale, causal=causal, bq=bq, bk=bk)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, n_k=n_k, **common),
@@ -280,10 +284,10 @@ def _flash_core_bwd(causal, bq, bk, interpret, res, dout):
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
             pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kb, 0)),
             pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kb, 0)),
-            pl.BlockSpec((1, bk), lambda gi, qb, kb: (gi, kb)),
+            pl.BlockSpec((1, 1, bk), lambda gi, qb, kb: (gi, 0, kb)),
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
-            pl.BlockSpec((1, bq), lambda gi, qb, kb: (gi, qb)),
-            pl.BlockSpec((1, bq), lambda gi, qb, kb: (gi, qb)),
+            pl.BlockSpec((1, bq, 1), lambda gi, qb, kb: (gi, qb, 0)),
+            pl.BlockSpec((1, bq, 1), lambda gi, qb, kb: (gi, qb, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
         out_shape=jax.ShapeDtypeStruct((g, sq, d), qf.dtype),
@@ -297,10 +301,10 @@ def _flash_core_bwd(causal, bq, bk, interpret, res, dout):
             pl.BlockSpec((1, bq, d), lambda gi, kb, qb: (gi, qb, 0)),
             pl.BlockSpec((1, bk, d), lambda gi, kb, qb: (gi, kb, 0)),
             pl.BlockSpec((1, bk, d), lambda gi, kb, qb: (gi, kb, 0)),
-            pl.BlockSpec((1, bk), lambda gi, kb, qb: (gi, kb)),
+            pl.BlockSpec((1, 1, bk), lambda gi, kb, qb: (gi, 0, kb)),
             pl.BlockSpec((1, bq, d), lambda gi, kb, qb: (gi, qb, 0)),
-            pl.BlockSpec((1, bq), lambda gi, kb, qb: (gi, qb)),
-            pl.BlockSpec((1, bq), lambda gi, kb, qb: (gi, qb)),
+            pl.BlockSpec((1, bq, 1), lambda gi, kb, qb: (gi, qb, 0)),
+            pl.BlockSpec((1, bq, 1), lambda gi, kb, qb: (gi, qb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda gi, kb, qb: (gi, kb, 0)),
@@ -373,7 +377,7 @@ def _prepare(q, k, v, kv_valid, bq, bk):
         kvm = jnp.pad(kv_valid.astype(bool), ((0, 0), (0, sk_p - sk))) \
             & valid[None, :]
     kvm = jnp.broadcast_to(kvm[:, None, :], (b, h, sk_p)) \
-        .reshape(b * h, sk_p).astype(jnp.int32)
+        .reshape(b * h, 1, sk_p).astype(jnp.int32)
     qf = q.reshape(b * h, sq_p, d)
     kf = k.reshape(b * h, sk_p, d)
     vf = v.reshape(b * h, sk_p, d)
@@ -417,4 +421,4 @@ def flash_attention_probe(q, k, v, *, kv_valid=None, causal: bool = True,
     qf, kf, vf, kvm, bq, bk = _prepare(q, k, v, kv_valid, bq, bk)
     out, _, probe = _fwd_call(qf, kf, vf, kvm, causal=causal, bq=bq, bk=bk,
                               interpret=interpret)
-    return out[:, :sq].reshape(b, h, sq, d), probe
+    return out[:, :sq].reshape(b, h, sq, d), probe.reshape(probe.shape[:2])

@@ -24,9 +24,12 @@ def main():
     import jax
     import numpy as np
     from repro.configs import get_config, reduced
+    from repro.launch import jaxcache
     from repro.models.layers import init_params
     from repro.models.transformer import model_template
     from repro.serving.engine import Request, ServingEngine
+
+    jaxcache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -34,9 +34,12 @@ def main():
     import jax
     from repro.configs import get_config, reduced
     from repro.data.pipeline import DataConfig
+    from repro.launch import jaxcache
     from repro.launch.mesh import make_mesh
     from repro.optim.adamw import OptConfig
     from repro.train.trainer import Trainer, TrainerConfig
+
+    jaxcache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:

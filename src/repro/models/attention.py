@@ -27,9 +27,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.configs.base import ArchConfig
-from repro.core.compat import shard_map
 from repro.models.layers import (P, apply_rope, repeat_kv, rms_norm,
                                  rotary_embedding)
 
@@ -91,12 +91,33 @@ def checkpoint_policy(name: str):
 
 
 def _flash_attention(q, k, v, kv_valid, causal: bool):
-    """(B,S,H,D)-layout adapter around kernels.ops.flash_attention."""
+    """(B,S,H,D)-layout adapter around kernels.ops.flash_attention.
+
+    Under a mesh the kernel runs per lane-local shard (batch on the data
+    axes, heads on the lane axis): GSPMD cannot partition a Pallas TPU
+    kernel, so callers route here only when :func:`_flash_fits_mesh`."""
     from repro.kernels import ops as kops
-    out = kops.flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), kv_valid=kv_valid, causal=causal)
-    return out.transpose(0, 2, 1, 3)
+
+    def local(q, k, v, kv_valid):
+        out = kops.flash_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), kv_valid=kv_valid, causal=causal)
+        return out.transpose(0, 2, 1, 3)
+
+    ctx = _MESH_CTX
+    if ctx is None:
+        return local(q, k, v, kv_valid)
+    from jax.sharding import PartitionSpec as PS
+    b_axes = tuple(ctx.batch_axes)
+    qkv = PS(b_axes, None, ctx.model_axis, None)
+    return shard_map(local, mesh=ctx.mesh,
+                     in_specs=(qkv, qkv, qkv, PS(b_axes, None)),
+                     out_specs=qkv, check_vma=False)(q, k, v, kv_valid)
+
+
+def _flash_fits_mesh(batch: int, heads: int) -> bool:
+    """No mesh, or one the kernel can run lane-local on."""
+    return _MESH_CTX is None or _lane_local_ok(batch, heads)
 
 
 def _lane_local_ok(batch: int, heads: int) -> bool:
@@ -192,7 +213,7 @@ def chunked_attention(q, k, v, q_pos, kv_valid, kv_offset=0, chunk=KV_CHUNK,
         threshold = CHUNK_THRESHOLD
 
     tri = triangular and s_len == t_len and kv_offset == 0
-    if tri and flash_route_enabled(use_flash):
+    if tri and flash_route_enabled(use_flash) and _flash_fits_mesh(b, h):
         # q_pos is arange(S) by the triangular contract, so the kernel's
         # index-vs-index causal mask is exactly this mask
         return _flash_attention(q, k, v, kv_valid, causal=True)
@@ -359,7 +380,8 @@ def cross_attention(cfg: ArchConfig, p: dict, x, memory, memory_valid=None):
     b, t = memory.shape[:2]
     if memory_valid is None:
         memory_valid = jnp.ones((b, t), bool)
-    if flash_route_enabled(getattr(cfg, "attn_flash", "auto")):
+    if flash_route_enabled(getattr(cfg, "attn_flash", "auto")) \
+            and _flash_fits_mesh(b, h):
         # pure kv_valid masking (no causal term) is exactly the kernel's
         # non-causal mode; fully-padded memory rows output zeros either way
         out = _flash_attention(q, k, v, memory_valid, causal=False)

@@ -61,8 +61,7 @@ def init_params(template, key, dtype=jnp.float32):
             return jnp.ones(p.shape, dtype)
         leaf_key = jax.random.fold_in(key, zlib_hash(path))
         if p.init == "fan_in":
-            fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
-            std = 1.0 / math.sqrt(max(fan_in, 1))
+            std = 1.0 / math.sqrt(max(_fan_in(p), 1))
         else:
             std = p.std
         return (jax.random.normal(leaf_key, p.shape, jnp.float32) * std).astype(dtype)
@@ -74,6 +73,18 @@ def init_params(template, key, dtype=jnp.float32):
             node = node.setdefault(k, {})
         node[path[-1]] = init_one(path, p)
     return out
+
+
+def _fan_in(p: P) -> int:
+    """Contracted size of a projection weight: the dims before its output,
+    after any leading stack axis. The output is the last dim, or the
+    (heads, head_dim) pair of a q/k/v projection; an output projection
+    contracts its (heads, head_dim) pair."""
+    if len(p.shape) < 2:
+        return p.shape[-1]
+    n_out = 2 if p.axes[-1] == "head_dim" else 1
+    n_in = 2 if p.axes[-n_out - 1] == "head_dim" else 1
+    return math.prod(p.shape[-n_out - n_in:-n_out])
 
 
 def zlib_hash(path) -> int:

@@ -23,10 +23,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from repro.configs.base import ArchConfig
-from repro.core.compat import shard_map
 from repro.models.layers import P, activation_fn
 from repro.models.sharding import MeshCtx
 

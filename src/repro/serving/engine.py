@@ -53,12 +53,13 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.kernels import ops as kernel_ops
 from repro.models import transformer as tf
+from repro.models.layers import abstract_params
 from repro.models.sharding import MeshCtx
 from repro.serving.scheduler import (Request, RejectReason, Scheduler,
                                      State)
 
 __all__ = ["Request", "RejectReason", "Scheduler", "State",
-           "ServingEngine", "DegradeLadder"]
+           "ServingEngine", "DegradeLadder", "decode_lowering"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,6 +141,29 @@ def _shared_decode(cfg: ArchConfig, mode: str):
                                          cache["lengths"])
         return next_tok, finite, new_cache
     return jax.jit(impl)
+
+
+def decode_lowering(cfg: ArchConfig, slots: int, max_seq: int,
+                    sharding=None):
+    """The engine's decode step (model precision, fp32 cache) lowered from
+    shapes alone: ``.compile().memory_analysis()`` sizes a slot pool before
+    any weights exist. The step donates nothing, so its output cache is a
+    second buffer on top of the arguments. ``sharding`` places the shapes
+    (e.g. on a described device for an ahead-of-time compile)."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def like(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    params = like(abstract_params(tf.model_template(cfg),
+                                  jnp.dtype(cfg.param_dtype)))
+    cache = like(tf.init_cache(cfg, slots, max_seq, abstract=True,
+                               cache_dtype=jnp.float32))
+    return _shared_decode(cfg, "fp32").lower(
+        params, cache, sds((slots, 1), jnp.int32), sds((slots,), bool),
+        sds((slots,), jnp.float32), sds((slots,), bool),
+        sds((2,), jnp.uint32))
 
 
 class ServingEngine:

@@ -22,9 +22,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as PS
-
-from repro.core.compat import shard_map
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:

@@ -8,17 +8,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 sys.path.insert(0, SRC)
 
-# Make `hypothesis` optional: the target container does not ship it and
-# installing packages is not allowed there, so fall back to the shim in
-# repro.testing.hypofallback (deterministic example generator implementing
-# the given/settings/strategies subset the suite uses). CI installs the
-# real thing; the shim only activates when the import fails.
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from repro.testing import hypofallback
-    hypofallback.install()
-
 
 def run_devices(code: str, n_devices: int = 8, x64: bool = False,
                 timeout: int = 600) -> str:

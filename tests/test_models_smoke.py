@@ -157,3 +157,26 @@ def test_head_padding_model_equivalent():
     dead = np.array([2, 3, 6, 7])
     assert float(jnp.abs(g["layers"]["attn"]["wq"][:, :, dead, :]).max()) == 0
     assert float(jnp.abs(g["layers"]["attn"]["wo"][:, dead]).max()) == 0
+
+
+@pytest.mark.parametrize("path,fan_in", [
+    (("layers", "attn", "wq"), "d_model"),
+    (("layers", "attn", "wk"), "d_model"),
+    (("layers", "attn", "wo"), "heads*head_dim"),
+    (("layers", "mlp", "w_up"), "d_model"),
+    (("layers", "mlp", "w_down"), "d_ff"),
+])
+def test_fan_in_init_scales_by_contracted_size(path, fan_in):
+    """fan_in weights have std 1/sqrt(contracted size): d_model for the
+    q/k/v projections (not n_heads), heads*head_dim for the output
+    projection (not head_dim). At the old scale stablelm-1.6b's attention
+    logits had std ~64 and its random model was chaotic under bf16."""
+    cfg = dataclasses.replace(get_config("stablelm-1.6b"), n_layers=1,
+                              vocab_size=256, d_ff=1024)
+    size = {"d_model": cfg.d_model, "d_ff": cfg.d_ff,
+            "heads*head_dim": cfg.n_heads * cfg.resolved_head_dim}[fan_in]
+    params = init_params(tf.model_template(cfg), jax.random.PRNGKey(0))
+    w = params
+    for k in path:
+        w = w[k]
+    np.testing.assert_allclose(float(jnp.std(w)), size ** -0.5, rtol=0.02)

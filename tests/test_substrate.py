@@ -293,7 +293,7 @@ def device_fn(g_loc, r_loc):
     (mean_g,), (new_r,) = compressed_psum((g_loc,), (r_loc,), mesh, ("data",))
     return mean_g, new_r
 
-from repro.core.compat import shard_map
+from jax import shard_map
 fn = shard_map(device_fn, mesh=mesh,
                in_specs=(PS("data"), PS("data")),
                out_specs=(PS(None), PS("data")), check_vma=False)

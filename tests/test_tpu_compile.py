@@ -1,0 +1,144 @@
+"""Ahead-of-time compiles for one TPU v5e chip, described and not attached.
+
+The TPU compiler ships with jax: it compiles for a chip described by
+``jax.experimental.topologies`` and refuses what the chip would refuse —
+Pallas block shapes the Mosaic lowering cannot tile, too much fast
+memory, a program that does not fit the device. Nothing runs here, so
+these tests say nothing about results or speed (the interpret-mode tests
+check results). They guard the main path at real widths:
+
+- the flash-attention kernel, forward and forward+backward;
+- the bf16 and int8 Pallas matmuls;
+- the full-width stablelm-1.6b serving decode step at the slot pool
+  ``chip_smoke.py`` uses, and its training step at the depth cut it uses.
+
+The topology is described inside a module fixture (never at import), so
+every xdist worker collects the same tests and only the one that runs
+this file loads the TPU library.
+"""
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from conftest import REPO
+
+sys.path.insert(0, REPO)
+import chip_smoke  # noqa: E402
+
+HBM_BYTES = 16_909_336_064        # bytes_limit of one v5e chip's HBM
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _flash_operands(one_chip):
+    q = _sds((1, 32, 2048, 64), jnp.bfloat16, one_chip)   # (B, H, S, D)
+    return q, _sds((1, 2048), jnp.bool_, one_chip)
+
+
+def test_flash_attention_forward_compiles(one_chip):
+    from repro.kernels.attention import flash_attention
+    q, kv_valid = _flash_operands(one_chip)
+
+    def fwd(q, k, v, kv_valid):
+        return flash_attention(q, k, v, kv_valid=kv_valid, causal=True,
+                               interpret=False)
+    compiled = jax.jit(fwd).lower(q, q, q, kv_valid).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def test_flash_attention_backward_compiles(one_chip):
+    from repro.kernels.attention import flash_attention
+    q, kv_valid = _flash_operands(one_chip)
+
+    def loss(q, k, v, kv_valid):
+        out = flash_attention(q, k, v, kv_valid=kv_valid, causal=True,
+                              interpret=False)
+        return out.astype(jnp.float32).sum()
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    text = grad.lower(q, q, q, kv_valid).compile().as_text()
+    # forward (lse residual) + the dQ and dK/dV kernels
+    assert text.count(KERNEL) >= 3
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_matmul_compiles(one_chip, kind):
+    from repro.kernels.matmul import matmul, matmul_int8
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.int8
+    a = _sds((1024, 2048), dtype, one_chip)
+    b = _sds((2048, 1024), dtype, one_chip)
+    fn = matmul if kind == "bf16" else matmul_int8
+    compiled = jax.jit(lambda a, b: fn(a, b, interpret=False)) \
+        .lower(a, b).compile()
+    assert KERNEL in compiled.as_text()
+
+
+def test_serving_decode_step_fits_one_chip(one_chip):
+    """stablelm-1.6b at full size, chip_smoke.py's slot pool: arguments
+    (fp32 params + cache), the undonated output cache and temporaries
+    fit one chip's HBM."""
+    from repro.configs import get_config
+    from repro.serving.engine import decode_lowering
+    cfg = get_config(chip_smoke.ARCH)
+    compiled = decode_lowering(cfg, chip_smoke.SLOTS, chip_smoke.MAX_SEQ,
+                               sharding=one_chip).compile()
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes > 0 and m.temp_size_in_bytes > 0
+    need = chip_smoke.footprint(compiled)
+    assert need <= HBM_BYTES, need
+
+
+def test_train_step_fits_one_chip_with_flash_kernel(one_chip, monkeypatch):
+    """chip_smoke.py's training cut (stablelm-1.6b widths, its depth,
+    batch and sequence) compiles with the flash kernel inside the step
+    and fits one chip with fp32 params and Adam state. The kernel route
+    and compiled (not interpreted) Pallas are what a TPU backend picks;
+    here the backend is the CPU, so the test picks them."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import attention
+    from repro.models.sharding import MeshCtx
+    from repro.optim.adamw import OptConfig
+    from repro.train import step as step_lib
+    monkeypatch.setattr(attention, "flash_route_enabled",
+                        lambda mode="auto": True)
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config(chip_smoke.ARCH),
+                              n_layers=chip_smoke.TRAIN_LAYERS)
+    bundle = step_lib.make_train_step(cfg, OptConfig(), MeshCtx(mesh=None))
+    state = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), bundle.abstract_state)
+    tok = _sds((chip_smoke.TRAIN_BATCH, chip_smoke.TRAIN_SEQ), jnp.int32,
+               one_chip)
+    compiled = jax.jit(bundle.step_fn).lower(
+        state, {"tokens": tok, "labels": tok}).compile()
+    assert KERNEL in compiled.as_text()
+    need = chip_smoke.footprint(compiled)
+    assert need <= HBM_BYTES, need
