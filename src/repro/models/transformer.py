@@ -173,23 +173,28 @@ def _attn_block(cfg, p, x, positions, cache=None, causal=True):
 
 
 def dense_block(cfg, p, x, positions, cache=None, causal=True, memory=None):
-    a, h, new_cache = _attn_block(cfg, p, x, positions, cache, causal)
+    with jax.named_scope("attn"):
+        a, h, new_cache = _attn_block(cfg, p, x, positions, cache, causal)
     if cfg.parallel_block:
-        return x + a + mlp(p["mlp"], h, cfg.activation), new_cache
+        with jax.named_scope("mlp"):
+            return x + a + mlp(p["mlp"], h, cfg.activation), new_cache
     x = x + a
     if memory is not None and "xattn" in p:
         hx = rms_norm(x, p["ln_x"], cfg.norm_eps)
         x = x + cross_attention(cfg, p["xattn"], hx, memory)
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    x = x + mlp(p["mlp"], h2, cfg.activation)
+    with jax.named_scope("mlp"):
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        x = x + mlp(p["mlp"], h2, cfg.activation)
     return x, new_cache
 
 
 def moe_layer(cfg, p, x, positions, ctx, cache=None):
-    a, _, new_cache = _attn_block(cfg, p, x, positions, cache)
+    with jax.named_scope("attn"):
+        a, _, new_cache = _attn_block(cfg, p, x, positions, cache)
     x = x + a
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    y, aux = moe_block(cfg, p["moe"], h2, ctx)
+    with jax.named_scope("mlp"):
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        y, aux = moe_block(cfg, p["moe"], h2, ctx)
     return x + y, aux, new_cache
 
 
@@ -386,10 +391,12 @@ def forward(cfg: ArchConfig, params: dict, tokens, *,
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     unembed = params["embed"].T if cfg.tie_embeddings else params["unembed"]
-    if head_fn is not None:
-        logits = head_fn(x, unembed.astype(compute_dtype))
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, unembed.astype(compute_dtype))
+    with jax.named_scope("head"):
+        if head_fn is not None:
+            logits = head_fn(x, unembed.astype(compute_dtype))
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x,
+                                unembed.astype(compute_dtype))
 
     if cache is not None:
         new_cache["lengths"] = lengths + s

@@ -44,12 +44,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Callable, Dict, List, Optional, Set
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.kernels import ops as kernel_ops
 from repro.models import transformer as tf
@@ -96,16 +98,20 @@ def _mode_cfg(cfg: ArchConfig, mode: str) -> ArchConfig:
 def _shared_prefill(cfg: ArchConfig, max_seq: int):
     """Batch-1 prefill on a fresh cache, shared across engine instances
     with the same (mesh-less) config — one compile per prompt shape
-    process-wide, not per engine."""
-    def impl(params, tokens, *, plen):
+    process-wide, not per engine. Returns the program (it lowers as
+    ``jit_serve_prefill``) and the set of prompt lengths it has seen."""
+    return _prefill_program(cfg, MeshCtx(mesh=None), max_seq), set()
+
+
+def _prefill_program(cfg: ArchConfig, ctx: MeshCtx, max_seq: int):
+    def serve_prefill(params, tokens, *, plen):
         del plen   # static: distinguishes trace shapes
         cache = tf.init_cache(cfg, 1, max_seq, cache_dtype=jnp.float32)
-        logits, _, new_cache = tf.forward(cfg, params, tokens,
-                                          ctx=MeshCtx(mesh=None),
+        logits, _, new_cache = tf.forward(cfg, params, tokens, ctx=ctx,
                                           cache=cache)
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_tok, new_cache
-    return jax.jit(impl, static_argnames=("plen",))
+    return jax.jit(serve_prefill, static_argnames=("plen",))
 
 
 @functools.lru_cache(maxsize=64)
@@ -114,33 +120,37 @@ def _shared_decode(cfg: ArchConfig, mode: str):
     the same (mesh-less) config. ``mode`` picks the degrade rung: fp32
     (the model's configured precision), bf16 compute, or bf16 compute
     with the int8 Pallas logits head."""
-    mcfg = _mode_cfg(cfg, mode)
     head_fn = None
     if mode == "int8":
         def head_fn(x, unembed):         # noqa: E306
             return kernel_ops.lm_head(x, unembed, compute_dtype="int8")
+    return _decode_program(_mode_cfg(cfg, mode), MeshCtx(mesh=None), head_fn)
 
-    def impl(params, cache, tokens, active_mask, temps, nan_mask, key):
-        logits, _, new_cache = tf.forward(mcfg, params, tokens,
-                                          ctx=MeshCtx(mesh=None),
+
+def _decode_program(mcfg: ArchConfig, ctx: MeshCtx, head_fn=None):
+    """The jitted decode step; it lowers as ``jit_serve_decode``."""
+    def serve_decode(params, cache, tokens, active_mask, temps, nan_mask,
+                     key):
+        logits, _, new_cache = tf.forward(mcfg, params, tokens, ctx=ctx,
                                           cache=cache, head_fn=head_fn)
-        last = logits[:, -1].astype(jnp.float32)
-        # fault-injection port: a real traced input, so flipping it never
-        # retraces (the mask is all-False in normal operation)
-        last = jnp.where(nan_mask[:, None], jnp.float32(jnp.nan), last)
-        finite = jnp.all(jnp.isfinite(last), axis=-1)
-        greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        scaled = last / jnp.maximum(temps, 1e-6)[:, None]
-        keys = jax.random.split(key, last.shape[0])
-        sampled = jax.vmap(jax.random.categorical)(keys, scaled) \
-            .astype(jnp.int32)
-        next_tok = jnp.where(temps > 0, sampled, greedy)
+        with jax.named_scope("sample"):
+            last = logits[:, -1].astype(jnp.float32)
+            # fault-injection port: a real traced input, so flipping it
+            # never retraces (the mask is all-False in normal operation)
+            last = jnp.where(nan_mask[:, None], jnp.float32(jnp.nan), last)
+            finite = jnp.all(jnp.isfinite(last), axis=-1)
+            greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            scaled = last / jnp.maximum(temps, 1e-6)[:, None]
+            keys = jax.random.split(key, last.shape[0])
+            sampled = jax.vmap(jax.random.categorical)(keys, scaled) \
+                .astype(jnp.int32)
+            next_tok = jnp.where(temps > 0, sampled, greedy)
         # inactive slots must not advance their lengths
         new_cache["lengths"] = jnp.where(active_mask,
                                          new_cache["lengths"],
                                          cache["lengths"])
         return next_tok, finite, new_cache
-    return jax.jit(impl)
+    return jax.jit(serve_decode)
 
 
 def decode_lowering(cfg: ArchConfig, slots: int, max_seq: int,
@@ -217,48 +227,23 @@ class ServingEngine:
             if self.ctx.mesh is None:
                 fn = _shared_decode(self.cfg, mode)
             else:                        # mesh engines keep their own jit
-                fn = self._build_mesh_decode(_mode_cfg(self.cfg, mode),
-                                             self.ctx)
+                fn = _decode_program(_mode_cfg(self.cfg, mode), self.ctx)
             self._decode_fns[mode] = fn
         return fn
-
-    def _build_mesh_decode(self, mcfg, ctx):
-        def impl(params, cache, tokens, active_mask, temps, nan_mask, key):
-            logits, _, new_cache = tf.forward(mcfg, params, tokens,
-                                              ctx=ctx, cache=cache)
-            last = logits[:, -1].astype(jnp.float32)
-            last = jnp.where(nan_mask[:, None], jnp.float32(jnp.nan), last)
-            finite = jnp.all(jnp.isfinite(last), axis=-1)
-            greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            scaled = last / jnp.maximum(temps, 1e-6)[:, None]
-            keys = jax.random.split(key, last.shape[0])
-            sampled = jax.vmap(jax.random.categorical)(keys, scaled) \
-                .astype(jnp.int32)
-            next_tok = jnp.where(temps > 0, sampled, greedy)
-            new_cache["lengths"] = jnp.where(active_mask,
-                                             new_cache["lengths"],
-                                             cache["lengths"])
-            return next_tok, finite, new_cache
-        return jax.jit(impl)
 
     def _prefill_one(self, tokens, plen):
         if self._prefill is None:
             if self.ctx.mesh is None:
                 self._prefill = _shared_prefill(self.cfg, self.max_seq)
             else:
-                cfg, ctx, max_seq = self.cfg, self.ctx, self.max_seq
-
-                def impl(params, toks, *, plen):
-                    del plen
-                    cache = tf.init_cache(cfg, 1, max_seq,
-                                          cache_dtype=jnp.float32)
-                    logits, _, new_cache = tf.forward(cfg, params, toks,
-                                                      ctx=ctx, cache=cache)
-                    next_tok = jnp.argmax(logits[:, -1],
-                                          axis=-1).astype(jnp.int32)
-                    return next_tok, new_cache
-                self._prefill = jax.jit(impl, static_argnames=("plen",))
-        return self._prefill(self.params, tokens, plen=plen)
+                self._prefill = (_prefill_program(self.cfg, self.ctx,
+                                                  self.max_seq), set())
+        fn, seen = self._prefill
+        self.counters["prefills"] += 1
+        if plen not in seen:
+            seen.add(plen)
+            self.counters["prefill_new_shapes"] += 1
+        return fn(self.params, tokens, plen=plen)
 
     @staticmethod
     def _batch_dim(key: str) -> int:
@@ -313,7 +298,13 @@ class ServingEngine:
         """Host-side slot/KV consistency: the I_SLOT_LEAK and I_KV_BOUNDS
         detectors. Runs before admission so reclaimed capacity is reusable
         in the same step."""
-        lengths = np.asarray(self.cache["lengths"])
+        with obs.span("serve.audit"):
+            with obs.span("serve.audit.wait"):
+                lengths = np.asarray(self.cache["lengths"])
+            self.counters["host_syncs"] += 1
+            self._audit_lengths(lengths, finished)
+
+    def _audit_lengths(self, lengths: np.ndarray, finished: List[Request]):
         for slot in list(self.active):
             req = self.active[slot]
             if req is None or req.state.terminal():
@@ -342,6 +333,7 @@ class ServingEngine:
         legacy engine (``hardened=False``) accepts everything."""
         if not self.hardened:
             req.submit_tick = self.tick
+            req.t_submit = time.perf_counter()
             self.sched.queue.append(req)
             return None
         return self.sched.submit(req, self.tick)
@@ -360,12 +352,10 @@ class ServingEngine:
                 self._finish(None, req, State.REJECTED,
                              RejectReason.PROMPT_TOO_LONG.value, finished)
                 continue
-            req.state = State.PREFILL
-            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            next_tok, single = self._prefill_one(toks, plen)
-            self.cache = self._scatter_slot(self.cache, single, slot)
-            tok = int(next_tok[0])
+            with obs.span("serve.admit", uid=req.uid, plen=plen):
+                tok = self._prefill_into(slot, req)
             req.out_tokens.append(tok)
+            req.t_tokens.append(req.t_first)
             req.first_token_tick = self.tick
             self._slot_len[slot] = plen
             self._slot_progress[slot] = self.tick
@@ -380,6 +370,22 @@ class ServingEngine:
                 self._finish(slot, req, State.EVICTED, "I_KV_CAPACITY",
                              finished)
 
+    def _prefill_into(self, slot: int, req: Request) -> int:
+        """Prefill ``req`` on its own, scatter its cache into ``slot``;
+        returns the first token, held on the host."""
+        req.t_admit = time.perf_counter()
+        req.state = State.PREFILL
+        with obs.span("serve.prefill.dispatch"):
+            toks = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            next_tok, single = self._prefill_one(toks, len(req.prompt))
+        with obs.span("serve.scatter.dispatch"):
+            self.cache = self._scatter_slot(self.cache, single, slot)
+        with obs.span("serve.prefill.wait"):
+            tok = int(next_tok[0])
+        self.counters["host_syncs"] += 1
+        req.t_first = time.perf_counter()
+        return tok
+
     def _pick_mode(self) -> str:
         if self.degrade is None:
             return "fp32"
@@ -390,26 +396,36 @@ class ServingEngine:
         return mode
 
     def _decode_step(self, finished: List[Request]):
-        tokens = np.zeros((self.slots, 1), np.int32)
-        mask = np.zeros((self.slots,), bool)
-        temps = np.zeros((self.slots,), np.float32)
-        nan_mask = np.zeros((self.slots,), bool)
-        for slot, req in self.active.items():
-            tokens[slot, 0] = req.out_tokens[-1] if req.out_tokens else 0
-            mask[slot] = slot not in self._suppress_slots
-            temps[slot] = req.temperature
-            nan_mask[slot] = slot in self._inject_nan_slots
-        self._inject_nan_slots.clear()
+        self.counters["decode_steps"] += 1
+        with obs.span("serve.decode.prepare"):
+            tokens = np.zeros((self.slots, 1), np.int32)
+            mask = np.zeros((self.slots,), bool)
+            temps = np.zeros((self.slots,), np.float32)
+            nan_mask = np.zeros((self.slots,), bool)
+            for slot, req in self.active.items():
+                tokens[slot, 0] = req.out_tokens[-1] if req.out_tokens else 0
+                mask[slot] = slot not in self._suppress_slots
+                temps[slot] = req.temperature
+                nan_mask[slot] = slot in self._inject_nan_slots
+            self._inject_nan_slots.clear()
+            self._key, sub = jax.random.split(self._key)
+            mode = self._pick_mode()
+            decode = self._decode_for(mode)
+        with obs.span("serve.decode.dispatch", mode=mode):
+            next_tok, finite, self.cache = decode(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(mask), jnp.asarray(temps), jnp.asarray(nan_mask),
+                sub)
+        with obs.span("serve.decode.wait"):
+            next_tok = np.asarray(next_tok)
+            finite = np.asarray(finite)
+        self.counters["host_syncs"] += 2
+        with obs.span("serve.decode.retire"):
+            self._retire(mask, next_tok, finite, finished)
 
-        self._key, sub = jax.random.split(self._key)
-        decode = self._decode_for(self._pick_mode())
-        next_tok, finite, self.cache = decode(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(mask), jnp.asarray(temps), jnp.asarray(nan_mask),
-            sub)
-        next_tok = np.asarray(next_tok)
-        finite = np.asarray(finite)
-
+    def _retire(self, mask: np.ndarray, next_tok: np.ndarray,
+                finite: np.ndarray, finished: List[Request]):
+        held = time.perf_counter()
         for slot, req in list(self.active.items()):
             if not mask[slot]:
                 pass                      # suppressed: no progress made
@@ -420,6 +436,7 @@ class ServingEngine:
             else:
                 tok = int(next_tok[slot])
                 req.out_tokens.append(tok)
+                req.t_tokens.append(held)
                 self._slot_len[slot] += 1
                 self._slot_progress[slot] = self.tick
                 if tok == req.eos_id \
@@ -448,19 +465,28 @@ class ServingEngine:
         """One engine step: run fault hooks, maintain the queue (deadline
         sheds), audit slot invariants, admit, decode one token for every
         active slot, retire. Returns requests that reached a terminal
-        state this step (DONE / EVICTED / TIMED_OUT / FAILED)."""
+        state this step (DONE / EVICTED / TIMED_OUT / FAILED).
+
+        The step is the root span ``serve.step`` (attrs ``tick``,
+        ``active`` after admission and ``admitted``)."""
         self.tick += 1
-        for hook in list(self.fault_hooks):
-            hook(self)
-        finished: List[Request] = []
-        if self.hardened:
-            for req in self.sched.tick(self.tick):
-                finished.append(req)
-                self.finished.append(req)
-            self._audit_slots(finished)
-        self._admit(finished)
-        if self.active:
-            self._decode_step(finished)
+        self.counters["steps"] += 1
+        with obs.span("serve.step", tick=self.tick) as sp:
+            for hook in list(self.fault_hooks):
+                hook(self)
+            finished: List[Request] = []
+            if self.hardened:
+                with obs.span("serve.sched"):
+                    for req in self.sched.tick(self.tick):
+                        finished.append(req)
+                        self.finished.append(req)
+                self._audit_slots(finished)
+            before = self.counters["prefills"]
+            self._admit(finished)
+            sp.attrs["active"] = len(self.active)
+            sp.attrs["admitted"] = self.counters["prefills"] - before
+            if self.active:
+                self._decode_step(finished)
         return finished
 
     def run_to_completion(self, max_steps: int = 1000) -> List[Request]:

@@ -28,6 +28,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import enum
+import time
 from typing import Deque, List, Optional
 
 import numpy as np
@@ -81,6 +82,13 @@ class Request:
       fit inside its remaining TTL is shed (``T_DEADLINE_INFEASIBLE``);
       one that overruns while queued or decoding is timed out
       (``T_DEADLINE_EXPIRED``) with whatever partial output it has.
+
+    Host-clock stamps (``time.perf_counter``; None until reached):
+    ``t_submit`` accepted into the queue, ``t_admit`` popped into a slot,
+    ``t_first`` first token held on the host, ``t_done`` terminal; and
+    ``t_tokens``, when each token of ``out_tokens`` was held. A retried
+    request keeps its ``t_submit``, loses its ``t_tokens`` with its
+    ``out_tokens`` and is stamped again on its next admission.
     """
     uid: int
     prompt: np.ndarray               # (S,) int32
@@ -97,8 +105,14 @@ class Request:
     finish_tick: int = -1
     retries: int = 0
     not_before: int = 0              # backoff eligibility gate (tick)
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_done: Optional[float] = None
+    t_tokens: list = dataclasses.field(default_factory=list)
 
     def finish(self, state: State, tick: int, reason: str = "") -> None:
+        self.t_done = time.perf_counter()
         self.state = state
         self.finish_tick = tick
         self.finish_reason = reason or self.finish_reason
@@ -142,11 +156,13 @@ class Scheduler:
             req.state = State.REJECTED
             req.finish_reason = reason.value
             req.finish_tick = now
+            req.t_done = time.perf_counter()
             self.rejected.append(req)
             self.counters[reason.value] += 1
             return reason
         req.state = State.QUEUED
         req.submit_tick = now
+        req.t_submit = time.perf_counter()
         self.queue.append(req)
         self.counters["accepted"] += 1
         return None
@@ -220,6 +236,7 @@ class Scheduler:
         """
         req.retries += 1
         req.out_tokens = []
+        req.t_tokens = []
         if req.retries > self.max_retries:
             req.finish(State.FAILED, now, f"{Q_QUARANTINED}:{cause}")
             self.quarantined.append(req)

@@ -191,8 +191,9 @@ def make_train_step(cfg: ArchConfig, opt_cfg: adamw.OptConfig, ctx: MeshCtx,
         else:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, batch)
-        new_params, new_opt, opt_metrics = adamw.update(
-            opt_cfg, grads, state["opt"], params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw.update(
+                opt_cfg, grads, state["opt"], params)
         metrics = dict(metrics, loss=loss, **opt_metrics)
         return {"params": new_params, "opt": new_opt}, metrics
 

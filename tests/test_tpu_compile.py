@@ -142,3 +142,40 @@ def test_train_step_fits_one_chip_with_flash_kernel(one_chip, monkeypatch):
     assert KERNEL in compiled.as_text()
     need = chip_smoke.footprint(compiled)
     assert need <= HBM_BYTES, need
+
+
+def test_flash_kernels_keep_their_names_inside_named_scopes(one_chip,
+                                                            monkeypatch):
+    """The train step's ``attn``/``mlp``/``optimizer`` scopes leave the
+    flash kernels' instruction names as the train cell's
+    ``flash_kernels`` patterns find them in a device trace: the forward
+    (twice, once more under remat), the dQ and the dK/dV kernel."""
+    import json
+    import re
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import attention
+    from repro.models.sharding import MeshCtx
+    from repro.optim.adamw import OptConfig
+    from repro.train import step as step_lib
+    monkeypatch.setattr(attention, "flash_route_enabled",
+                        lambda mode="auto": True)
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config(chip_smoke.ARCH), n_layers=1,
+                              vocab_size=1024)
+    bundle = step_lib.make_train_step(cfg, OptConfig(), MeshCtx(mesh=None))
+    state = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), bundle.abstract_state)
+    tok = _sds((1, 256), jnp.int32, one_chip)
+    lowered = jax.jit(bundle.step_fn).lower(state,
+                                            {"tokens": tok, "labels": tok})
+    scoped = lowered.as_text(debug_info=True)
+    assert "attn/" in scoped and "optimizer/" in scoped
+    lines = [ln.strip() for ln in lowered.compile().as_text().splitlines()]
+    with open(os.path.join(REPO, "bench", "workloads",
+                           "starcoder2-3b.train-4k.json")) as f:
+        patterns = json.load(f)["flash_kernels"]
+    found = {k: sum(1 for ln in lines if re.search(p, ln))
+             for k, p in patterns.items()}
+    assert found == {"fwd": 2, "dq": 1, "dkv": 1}, found
