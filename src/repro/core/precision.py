@@ -68,8 +68,8 @@ class Policy:
     cache_dtype: str = "bfloat16"
     lmul: int = 1                # register grouping the Ara analogue uses;
                                  # kernels scale block shapes by it
-    attn_bq: int = 128           # flash-attention q/kv block shapes —
-    attn_bk: int = 128           # the blockwise kernel's tile knobs
+    attn_bq: int | None = None   # flash-attention q/kv block shapes;
+    attn_bk: int | None = None   # None takes the kernel's shape rule
 
     def peak_flops(self) -> float:
         return PEAKS_FLOPS[self.compute_dtype]

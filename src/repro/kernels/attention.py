@@ -16,13 +16,19 @@ Contract (normative — see docs/kernels.md):
   padded internally to block multiples (padded keys are masked, padded
   query rows are sliced off) — ragged lengths are first-class, and
   genuinely unsupported inputs raise ``ValueError`` naming the shapes.
+- Block shapes left as None follow ``default_block``: the largest of
+  1024, 512, 256, 128 that pads the length no further than a multiple
+  of 128 does and whose (block, D) tile fits 512 KiB, the length itself
+  below 128. A grid step costs fixed pipeline time, so the largest tile
+  that adds no padding runs fastest.
 - Causal masking compares raw row/column indices (``q_pos >= k_pos``),
   matching ``ref.flash_attention_ref``.
 - Causal block-skip is real: KV blocks strictly above the diagonal issue
   NO MXU work (``pl.when`` around the whole block body), and
   ``flash_attention_probe`` returns the per-(batch*head, q-block) count of
   blocks that did issue — the triangular case provably runs O(n_k/2)
-  iterations per q row-block (asserted in tests).
+  iterations per q row-block (asserted in tests). The index maps clamp a
+  skipped step onto the block already resident, so it fetches nothing.
 - Fully-masked rows (every key invalid — e.g. cross-attention padding)
   output ZEROS, with lse pinned to NEG_INF and zero gradients; never
   ``acc / max(l, eps)`` garbage.
@@ -30,7 +36,8 @@ Contract (normative — see docs/kernels.md):
   with two Pallas backward kernels (dQ; dK+dV), both skipping
   fully-masked blocks, both accumulating in fp32 regardless of input
   dtype. Block sizes ride on ``core.precision.Policy`` (``attn_bq`` /
-  ``attn_bk``) through ``kernels.ops.flash_attention``.
+  ``attn_bk``, None for the rule above) through
+  ``kernels.ops.flash_attention``.
 """
 from __future__ import annotations
 
@@ -54,6 +61,26 @@ def _causal_need(qb, kb, bq: int, bk: int):
     q row-block qb? False means every (q, k) pair in the tile has q < k —
     the block is fully masked and must issue no MXU work."""
     return kb * bk <= qb * bq + bq - 1
+
+
+def _kv_index(causal: bool, bq: int, bk: int):
+    """KV block index map of the forward and dQ grids. Under the causal
+    skip a step past q row-block qb's last needed KV block names that
+    block again, and the pipeline, which fetches only when an index
+    changes, copies nothing for it."""
+    if not causal:
+        return lambda qb, kb: kb
+    return lambda qb, kb: jnp.minimum(kb, (qb * bq + bq - 1) // bk)
+
+
+def _q_index(causal: bool, bq: int, bk: int, n_q: int):
+    """q row-block index map of the dK/dV grid. Under the causal skip a
+    step before KV block kb's first needed row-block names that block;
+    capped at the last row-block for a KV block no row needs (Sk > Sq)."""
+    if not causal:
+        return lambda kb, qb: qb
+    return lambda kb, qb: jnp.minimum(jnp.maximum(qb, (kb * bk) // bq),
+                                      n_q - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -132,15 +159,16 @@ def _fwd_call(qf, kf, vf, kvm, *, causal: bool, bq: int, bk: int,
     sk = kf.shape[1]
     n_q, n_k = sq // bq, sk // bk
     scale = 1.0 / math.sqrt(d)
+    kv = _kv_index(causal, bq, bk)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, n_k=n_k),
         grid=(g, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
-            pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kb, 0)),
-            pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kb, 0)),
-            pl.BlockSpec((1, 1, bk), lambda gi, qb, kb: (gi, 0, kb)),
+            pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kv(qb, kb), 0)),
+            pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kv(qb, kb), 0)),
+            pl.BlockSpec((1, 1, bk), lambda gi, qb, kb: (gi, 0, kv(qb, kb))),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
@@ -277,14 +305,15 @@ def _flash_core_bwd(causal, bq, bk, interpret, res, dout):
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
     common = dict(scale=scale, causal=causal, bq=bq, bk=bk)
+    kv = _kv_index(causal, bq, bk)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, n_k=n_k, **common),
         grid=(g, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
-            pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kb, 0)),
-            pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kb, 0)),
-            pl.BlockSpec((1, 1, bk), lambda gi, qb, kb: (gi, 0, kb)),
+            pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kv(qb, kb), 0)),
+            pl.BlockSpec((1, bk, d), lambda gi, qb, kb: (gi, kv(qb, kb), 0)),
+            pl.BlockSpec((1, 1, bk), lambda gi, qb, kb: (gi, 0, kv(qb, kb))),
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
             pl.BlockSpec((1, bq, 1), lambda gi, qb, kb: (gi, qb, 0)),
             pl.BlockSpec((1, bq, 1), lambda gi, qb, kb: (gi, qb, 0)),
@@ -294,17 +323,18 @@ def _flash_core_bwd(causal, bq, bk, interpret, res, dout):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, kvm, dout, lse, delta)
+    qi = _q_index(causal, bq, bk, n_q)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, n_q=n_q, **common),
         grid=(g, n_k, n_q),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda gi, kb, qb: (gi, qb, 0)),
+            pl.BlockSpec((1, bq, d), lambda gi, kb, qb: (gi, qi(kb, qb), 0)),
             pl.BlockSpec((1, bk, d), lambda gi, kb, qb: (gi, kb, 0)),
             pl.BlockSpec((1, bk, d), lambda gi, kb, qb: (gi, kb, 0)),
             pl.BlockSpec((1, 1, bk), lambda gi, kb, qb: (gi, 0, kb)),
-            pl.BlockSpec((1, bq, d), lambda gi, kb, qb: (gi, qb, 0)),
-            pl.BlockSpec((1, bq, 1), lambda gi, kb, qb: (gi, qb, 0)),
-            pl.BlockSpec((1, bq, 1), lambda gi, kb, qb: (gi, qb, 0)),
+            pl.BlockSpec((1, bq, d), lambda gi, kb, qb: (gi, qi(kb, qb), 0)),
+            pl.BlockSpec((1, bq, 1), lambda gi, kb, qb: (gi, qi(kb, qb), 0)),
+            pl.BlockSpec((1, bq, 1), lambda gi, kb, qb: (gi, qi(kb, qb), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda gi, kb, qb: (gi, kb, 0)),
@@ -349,9 +379,34 @@ def _validate(q, k, v, kv_valid):
             f"(B, Sk) = {(q.shape[0], k.shape[2])}")
 
 
-def _block_geometry(sq: int, sk: int, bq: int, bk: int):
-    """Clamp blocks to the (unpadded) lengths, then round lengths UP to
-    block multiples — the padded tail is masked, never asserted away."""
+_BLOCKS = (1024, 512, 256, 128)
+# one (block, D) operand tile at most: 1024 x 128 at fp32 or 1024 x 256 at
+# bf16 still fit the default scoped VMEM of a v5e, 1024 x 256 at fp32 not
+_TILE_BYTES = 512 * 1024
+
+
+def default_block(length: int, row_bytes: int) -> int:
+    """The block a length gets when the caller names none: the largest of
+    ``_BLOCKS`` whose padded length is no longer than the length padded
+    to a multiple of 128 and whose tile of ``row_bytes``-byte rows (D x
+    itemsize) fits ``_TILE_BYTES``; the length itself below 128. Each
+    grid step costs fixed pipeline time, so fewer, larger tiles win until
+    they add padding (a 1601-key memory keeps 128: 256 would pad 128 keys
+    more) or outgrow the fast memory."""
+    if length < 128:
+        return length
+    floor = -(-length // 128) * 128
+    return next((b for b in _BLOCKS if -(-length // b) * b <= floor
+                 and b * row_bytes <= _TILE_BYTES), _BLOCKS[-1])
+
+
+def _block_geometry(sq: int, sk: int, bq: int | None, bk: int | None,
+                    row_bytes: int):
+    """Pick blocks (``default_block`` where None), clamp them to the
+    (unpadded) lengths, then round lengths UP to block multiples — the
+    padded tail is masked, never asserted away."""
+    bq = default_block(sq, row_bytes) if bq is None else bq
+    bk = default_block(sk, row_bytes) if bk is None else bk
     bq = max(1, min(bq, sq))
     bk = max(1, min(bk, sk))
     sq_p = -(-sq // bq) * bq
@@ -364,7 +419,8 @@ def _prepare(q, k, v, kv_valid, bq, bk):
     operands plus the geometry needed to undo it."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq, bk, sq_p, sk_p = _block_geometry(sq, sk, bq, bk)
+    bq, bk, sq_p, sk_p = _block_geometry(sq, sk, bq, bk,
+                                         d * q.dtype.itemsize)
     if sq_p != sq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
     if sk_p != sk:
@@ -394,12 +450,14 @@ def _flash_padded(q, k, v, kv_valid, *, causal, bq, bk, interpret):
 
 
 def flash_attention(q, k, v, *, kv_valid=None, causal: bool = True,
-                    bq: int = 128, bk: int = 128, interpret: bool = False):
+                    bq: int | None = None, bk: int | None = None,
+                    interpret: bool = False):
     """Blockwise attention with a training-grade VJP.
 
     q (B,H,Sq,D); k,v (B,H,Sk,D); kv_valid (B,Sk) bool or None ->
     (B,H,Sq,D). Differentiable w.r.t. q, k, v. Ragged Sq/Sk are padded to
     block multiples internally; rows with no valid key return zeros.
+    bq/bk None take ``default_block`` of Sq/Sk and the head row's bytes.
     """
     _validate(q, k, v, kv_valid)
     return _flash_padded(q, k, v, kv_valid, causal=causal, bq=bq, bk=bk,
@@ -407,7 +465,7 @@ def flash_attention(q, k, v, *, kv_valid=None, causal: bool = True,
 
 
 def flash_attention_probe(q, k, v, *, kv_valid=None, causal: bool = True,
-                          bq: int = 128, bk: int = 128,
+                          bq: int | None = None, bk: int | None = None,
                           interpret: bool = False):
     """Forward pass plus the block-skip witness.
 

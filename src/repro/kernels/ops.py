@@ -73,7 +73,8 @@ def conv2d(x, w, *, policy: Policy | None = None, **kw):
 def flash_attention(q, k, v, *, policy: Policy | None = None, **kw):
     """Blockwise flash attention with a training-grade VJP (see
     kernels/attention.py). ``policy.attn_bq``/``attn_bk`` pick the block
-    shapes; ``kv_valid`` passes through uncast (it is a mask, not data)."""
+    shapes (None: ``attention.default_block``); ``kv_valid`` passes
+    through uncast (it is a mask, not data)."""
     kw.setdefault("interpret", _default_interpret())
     if policy is not None:
         kw.setdefault("bq", policy.attn_bq)

@@ -5,7 +5,9 @@ routing in models/attention.py:
 
 - custom-VJP backward vs the jnp oracle's jax.grad across causal x dtype
   x ragged lengths (tol 1e-5 fp32 / 2e-2 bf16),
-- the causal block-skip probe (fully masked KV blocks issue no work),
+- the causal block-skip probe (fully masked KV blocks issue no work) and
+  the index maps that fetch nothing for a skipped block,
+- the block rule for callers that name no block shape,
 - internal pad-to-block-multiple instead of the old bare assert, with
   ValueError naming the shapes for genuinely unsupported inputs,
 - the zeros-for-dead-rows convention (output AND gradients) on every
@@ -22,6 +24,7 @@ import pytest
 
 from repro.core.precision import Policy
 from repro.kernels import ops, ref
+from repro.kernels import attention as K
 from repro.kernels.attention import flash_attention_probe
 from repro.models import attention as A
 
@@ -92,19 +95,74 @@ def test_flash_grad_under_jit_and_vjp_composition(rng):
     assert grad.shape == q.shape and bool(jnp.any(grad != 0))
 
 
+@pytest.mark.parametrize("length,row_bytes,block", [
+    (4096, 256, 1024),     # the train cell: D 128 at bf16
+    (1024, 256, 1024),
+    (1536, 256, 512),      # 1024 would pad 512 keys
+    (700, 256, 256),
+    (1601, 256, 128),      # a vision memory: 256 would pad 128 keys
+    (64, 256, 64),         # below 128: the length itself
+    (4096, 512, 1024),     # D 256 at bf16
+    (4096, 1024, 512),     # D 256 at fp32: a 1024-row tile is 1 MiB
+    (4096, 4096, 128),     # D 1024 at fp32: no tile fits, the smallest
+])
+def test_default_block_rule(length, row_bytes, block):
+    """No named block: the largest of 1024/512/256/128 that pads no
+    further than the 128-multiple and whose tile fits 512 KiB, the
+    length itself below 128."""
+    assert K.default_block(length, row_bytes) == block
+
+
+@pytest.mark.parametrize("s,causal,dead_row", [
+    (2048, True, False),   # a 2x2 grid of 1024 blocks
+    (700, True, True),     # 256 blocks, ragged tail, one batch row dead
+    (700, False, True),
+])
+def test_flash_default_blocks_match_ref(s, causal, dead_row, rng):
+    """Forward and grads at the blocks the kernel picks for itself."""
+    b, h, d = 2, 1, 16
+    q, k, v = (_mk(rng, (b, h, s, d), jnp.float32) for _ in range(3))
+    kv_valid = jnp.asarray(rng.rand(b, s) < 0.9)
+    if dead_row:
+        kv_valid = kv_valid.at[0].set(False)
+
+    def run(fn, **kw):
+        def loss(q, k, v):
+            o = fn(q, k, v, kv_valid=kv_valid, causal=causal, **kw)
+            return jnp.sum(jnp.sin(o)), o
+        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+        return o, g
+
+    o_k, g_k = run(ops.flash_attention, interpret=True)
+    o_r, g_r = run(ref.flash_attention_ref)
+    tol = GRAD_TOL[jnp.float32]
+    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
+                               rtol=tol, atol=tol * 4)
+    if dead_row:
+        assert float(jnp.abs(o_k[0]).max()) == 0.0
+    for name, a, b_ in zip("qkv", g_k, g_r):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=tol, atol=tol * 4,
+                                   err_msg=f"d{name}")
+
+
 # ---------------------------------------------------------------------------
 # Causal block-skip probe
 # ---------------------------------------------------------------------------
 
 
-def test_causal_skip_triangular_iterations(rng):
+@pytest.mark.parametrize("b,h,s,d,blk", [
+    (2, 3, 128, 16, 16),
+    (1, 2, 1536, 8, None),     # default blocks: 512, a 3x3 grid
+])
+def test_causal_skip_triangular_iterations(b, h, s, d, blk, rng):
     """Causal grids issue exactly n_k*(n_k+1)/2 block iterations per
     (batch*head) — the docstring's skip promise, counted in-kernel."""
-    b, h, s, d, blk = 2, 3, 128, 16, 16
     q = _mk(rng, (b, h, s, d), jnp.float32)
     out, probe = flash_attention_probe(q, q, q, causal=True, bq=blk, bk=blk,
                                        interpret=True)
-    n = s // blk
+    n = s // (blk or K.default_block(s, d * 4))
     assert int(probe.sum()) == b * h * n * (n + 1) // 2
     # per q-block: block i visits exactly i+1 KV blocks
     per_block = np.asarray(probe).reshape(b * h, n)
@@ -113,6 +171,38 @@ def test_causal_skip_triangular_iterations(rng):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref.flash_attention_ref(q, q, q)),
         rtol=1e-5, atol=1e-5)
+
+
+def _fetches(indices):
+    """Copies a pipeline that fetches only on a change of index makes."""
+    return sum(1 for i, x in enumerate(indices) if i == 0
+               or x != indices[i - 1])
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", [
+    (1024, 1024, 128, 128), (1024, 1024, 256, 128), (1024, 1024, 128, 256),
+    (512, 1024, 128, 128),     # Sk > Sq: some KV blocks no row needs
+])
+def test_causal_index_maps_fetch_only_live_blocks(sq, sk, bq, bk):
+    """A step the causal skip drops names the block already resident: the
+    forward/dQ grid fetches K/V once per live block of each q row-block,
+    the dK/dV grid Q/dO/lse/delta once per live block of each KV block
+    (at least once: a block is resident at every step), and every live
+    step names its own block."""
+    n_q, n_k = sq // bq, sk // bk
+    kv = K._kv_index(True, bq, bk)
+    qi = K._q_index(True, bq, bk, n_q)
+    live = [[bool(K._causal_need(qb, kb, bq, bk)) for kb in range(n_k)]
+            for qb in range(n_q)]
+    for qb in range(n_q):
+        idx = [int(kv(qb, kb)) for kb in range(n_k)]
+        assert all(idx[kb] == kb for kb in range(n_k) if live[qb][kb])
+        assert _fetches(idx) == sum(live[qb])
+    for kb in range(n_k):
+        idx = [int(qi(kb, qb)) for qb in range(n_q)]
+        assert all(0 <= i < n_q for i in idx)
+        assert all(idx[qb] == qb for qb in range(n_q) if live[qb][kb])
+        assert _fetches(idx) == max(1, sum(row[kb] for row in live))
 
 
 def test_non_causal_runs_full_grid(rng):

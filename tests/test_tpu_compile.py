@@ -7,7 +7,9 @@ memory, a program that does not fit the device. Nothing runs here, so
 these tests say nothing about results or speed (the interpret-mode tests
 check results). They guard the main path at real widths:
 
-- the flash-attention kernel, forward and forward+backward;
+- the flash-attention kernel, forward and forward+backward, at the
+  blocks it picks for itself (1024 at the train cell's 4096 x 128
+  heads: the tiles must fit the default scoped VMEM);
 - the bf16 and int8 Pallas matmuls;
 - the full-width stablelm-1.6b serving decode step at the slot pool
   ``chip_smoke.py`` uses, and its training step at the depth cut it uses.
@@ -58,14 +60,26 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _flash_operands(one_chip):
-    q = _sds((1, 32, 2048, 64), jnp.bfloat16, one_chip)   # (B, H, S, D)
-    return q, _sds((1, 2048), jnp.bool_, one_chip)
+# (B, H, S, D) and dtype: chip_smoke.py's stablelm heads, the train
+# cell's starcoder2-3b step (32 padded heads of 128 at batch 2 x 4096,
+# 1024 blocks), and the widest fp32 head the block rule caps at 512
+FLASH_OPERANDS = {
+    "stablelm-2k": ((1, 32, 2048, 64), jnp.bfloat16),
+    "train-4k": ((2, 32, 4096, 128), jnp.bfloat16),
+    "d256-fp32": ((1, 8, 4096, 256), jnp.float32),
+}
 
 
-def test_flash_attention_forward_compiles(one_chip):
+def _flash_operands(one_chip, case):
+    shape, dtype = FLASH_OPERANDS[case]
+    q = _sds(shape, dtype, one_chip)
+    return q, _sds((shape[0], shape[2]), jnp.bool_, one_chip)
+
+
+@pytest.mark.parametrize("case", FLASH_OPERANDS)
+def test_flash_attention_forward_compiles(one_chip, case):
     from repro.kernels.attention import flash_attention
-    q, kv_valid = _flash_operands(one_chip)
+    q, kv_valid = _flash_operands(one_chip, case)
 
     def fwd(q, k, v, kv_valid):
         return flash_attention(q, k, v, kv_valid=kv_valid, causal=True,
@@ -74,9 +88,10 @@ def test_flash_attention_forward_compiles(one_chip):
     assert KERNEL in compiled.as_text()
 
 
-def test_flash_attention_backward_compiles(one_chip):
+@pytest.mark.parametrize("case", FLASH_OPERANDS)
+def test_flash_attention_backward_compiles(one_chip, case):
     from repro.kernels.attention import flash_attention
-    q, kv_valid = _flash_operands(one_chip)
+    q, kv_valid = _flash_operands(one_chip, case)
 
     def loss(q, k, v, kv_valid):
         out = flash_attention(q, k, v, kv_valid=kv_valid, causal=True,
