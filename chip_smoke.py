@@ -3,7 +3,8 @@
 
     python chip_smoke.py             # one chip: serving, training, vector engine
     python chip_smoke.py --chips 4   # four chips: ClusterEngine and the 2x2
-                                     # meshed train step, each against one device
+                                     # meshed train steps (dense and MoE),
+                                     # each against one device
 
 One chip runs three phases through the entry points a user calls:
 
@@ -28,6 +29,7 @@ import dataclasses
 import json
 import math
 import os
+import re
 import sys
 import time
 
@@ -39,6 +41,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
+from repro.configs.base import ArchConfig  # noqa: E402
 from repro.configs.ara import AraConfig  # noqa: E402
 from repro.core import isa  # noqa: E402
 from repro.core.cluster import ClusterEngine  # noqa: E402
@@ -80,6 +83,11 @@ TRAIN_LAYERS, TRAIN_SEQ, TRAIN_BATCH, TRAIN_STEPS = 2, 2048, 2, 3
 # 2x2 mesh vs one device: same init, same batch; the bf16 contractions
 # are split across lanes, so the summation order (not the math) differs
 MESH_LOSS_RTOL = 1e-2
+# the MoE train cell's config (one period of 4 layers, 8 of 64 experts
+# held) on the 2x2 mesh: its grouped products run in moe_held_mesh's
+# shard_map, the held experts split over the lanes
+MOE_CONFIG = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "bench", "configs", "mellum2-12b-l4-ep8.json")
 
 # vector engine: the paper's marquee DGEMM point (n=256 on 16 lanes). One
 # program per call: the batched step evaluates every opcode branch per
@@ -245,8 +253,8 @@ def make_trainer(cfg, *, seq_len: int, batch: int, steps: int, mesh=None):
 
 def compile_step(trainer, seq_len: int, batch: int):
     tok = jax.ShapeDtypeStruct((batch, seq_len), jnp.int32)
-    return trainer.step_fn.lower(trainer.bundle.abstract_state,
-                                 {"tokens": tok, "labels": tok}).compile()
+    return trainer.jit_step.lower(trainer.bundle.abstract_state,
+                                  {"tokens": tok, "labels": tok}).compile()
 
 
 def run_losses(trainer, label: str):
@@ -405,6 +413,13 @@ def main(argv=None) -> int:
         hlo = phase_mesh_train(cut, seq_len=TRAIN_SEQ, batch=TRAIN_BATCH,
                                steps=2, rtol=MESH_LOSS_RTOL)
         assert "tpu_custom_call" in hlo, "meshed step runs no Pallas kernel"
+        with open(MOE_CONFIG) as f:
+            moe = ArchConfig(**json.load(f)["arch"])
+        hlo = phase_mesh_train(moe, seq_len=TRAIN_SEQ, batch=TRAIN_BATCH,
+                               steps=2, rtol=MESH_LOSS_RTOL)
+        assert re.search(r"%gmm[.0-9]* = ", hlo) \
+            and re.search(r"%tgmm[.0-9]* = ", hlo), \
+            "meshed MoE step runs no grouped product"
     else:
         phase_serving(full, slots=SLOTS, max_seq=MAX_SEQ,
                       prompt_lens=PROMPT_LENS, n_requests=N_REQUESTS,

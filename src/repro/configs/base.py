@@ -51,10 +51,18 @@ class MoEConfig:
     # pad the expert table to the next lane multiple with router-masked dead
     # experts (model-equivalent) so EP applies to non-divisible counts
     pad_experts_to: int = 0
+    # the router's published width when this chip holds only experts
+    # [0, n_experts) of them (one rank of an expert-parallel deployment);
+    # 0 -> n_experts, every expert held here
+    n_routed_experts: int = 0
 
     @property
     def n_experts_padded(self) -> int:
         return max(self.pad_experts_to, self.n_experts)
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +87,25 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as Hugging Face's
+    ``_compute_yarn_parameters`` reads it: frequencies ramp from
+    interpolated (divided by ``factor``) to extrapolated over the
+    dimensions whose wavelengths fall between ``beta_fast`` and
+    ``beta_slow`` rotations in ``original_max_position_embeddings``;
+    cos and sin are multiplied by ``attention_factor``."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+_NESTED = {"moe": MoEConfig, "mla": MLAConfig, "ssm": SSMConfig,
+           "yarn": YarnConfig}
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str                  # dense | moe | ssm | hybrid | vlm | audio
@@ -98,6 +125,12 @@ class ArchConfig:
     # (model-equivalent incl. gradients) so attention TP-shards when
     # n_heads doesn't divide the lane axis (barber's-pole realignment)
     pad_heads_to: int = 0
+    # layers repeat this period of kinds, "sliding" (attends to the last
+    # ``sliding_window`` positions, itself included) or "full"; () -> all
+    # full. ``yarn`` scales the rotary embedding of the full layers only.
+    layer_kinds: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    yarn: Optional[YarnConfig] = None
     # --- MoE ---
     moe: MoEConfig = MoEConfig()
     # --- SSM / hybrid ---
@@ -133,6 +166,15 @@ class ArchConfig:
     scan_layers: bool = True
     # long-context support marker (sub-quadratic token mixing)
     subquadratic: bool = False
+
+    def __post_init__(self):
+        """Nested groups given as dicts (a JSON config file) become their
+        dataclasses; a list of layer kinds becomes a tuple."""
+        for key, typ in _NESTED.items():
+            val = getattr(self, key)
+            if isinstance(val, dict):
+                object.__setattr__(self, key, typ(**val))
+        object.__setattr__(self, "layer_kinds", tuple(self.layer_kinds))
 
     # ---- derived ----
     @property
@@ -203,7 +245,7 @@ class ArchConfig:
             n_moe_layers = self.n_layers - m.n_dense_layers
             total += m.n_dense_layers * 3 * d * dense_ff
             total += n_moe_layers * (m.n_experts + m.n_shared_experts) * 3 * d * m.expert_d_ff
-            total += n_moe_layers * d * m.n_experts  # router
+            total += n_moe_layers * d * m.router_width  # router
         if self.family == "hybrid" and self.shared_attn_block:
             # one shared attention+FFN block (weight-tied)
             total += d * (self.n_heads * hd) + 2 * d * self.n_kv_heads * hd \

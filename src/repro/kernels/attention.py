@@ -29,6 +29,12 @@ Contract (normative — see docs/kernels.md):
   blocks that did issue — the triangular case provably runs O(n_k/2)
   iterations per q row-block (asserted in tests). The index maps clamp a
   skipped step onto the block already resident, so it fetches nothing.
+- A static ``window`` (causal only) adds the band's lower edge: q sees k
+  iff ``0 <= q - k < window``, in the skip predicate, the masks and the
+  index maps of all three kernels, so blocks below the band are neither
+  computed nor fetched (15 of 64 live at 8192 keys, 1024 blocks and
+  window 1024, against 36 causal). ``window=None`` traces the causal
+  program unchanged.
 - Fully-masked rows (every key invalid — e.g. cross-attention padding)
   output ZEROS, with lse pinned to NEG_INF and zero gradients; never
   ``acc / max(l, eps)`` garbage.
@@ -56,31 +62,59 @@ NEG_INF = -1e30
 _DEAD_ROW = NEG_INF * 0.5
 
 
-def _causal_need(qb, kb, bq: int, bk: int):
+def _causal_need(qb, kb, bq: int, bk: int, window=None):
     """Traced predicate: does KV block kb intersect the causal triangle of
-    q row-block qb? False means every (q, k) pair in the tile has q < k —
-    the block is fully masked and must issue no MXU work."""
-    return kb * bk <= qb * bq + bq - 1
+    q row-block qb (and, with a ``window``, the band q - k < window)?
+    False means every (q, k) pair in the tile is masked — the block must
+    issue no MXU work."""
+    need = kb * bk <= qb * bq + bq - 1
+    if window is not None:
+        need = need & (kb * bk + bk - 1 > qb * bq - window)
+    return need
 
 
-def _kv_index(causal: bool, bq: int, bk: int):
+def _kv_index(causal: bool, bq: int, bk: int, window=None):
     """KV block index map of the forward and dQ grids. Under the causal
     skip a step past q row-block qb's last needed KV block names that
-    block again, and the pipeline, which fetches only when an index
-    changes, copies nothing for it."""
+    block again, and a step before its first needed block (the window's
+    lower edge) names that one; the pipeline, which fetches only when an
+    index changes, copies nothing for either."""
     if not causal:
         return lambda qb, kb: kb
-    return lambda qb, kb: jnp.minimum(kb, (qb * bq + bq - 1) // bk)
+    if window is None:
+        return lambda qb, kb: jnp.minimum(kb, (qb * bq + bq - 1) // bk)
+    return lambda qb, kb: jnp.clip(
+        kb, jnp.maximum(qb * bq - window + 1, 0) // bk,
+        (qb * bq + bq - 1) // bk)
 
 
-def _q_index(causal: bool, bq: int, bk: int, n_q: int):
+def _q_index(causal: bool, bq: int, bk: int, n_q: int, window=None):
     """q row-block index map of the dK/dV grid. Under the causal skip a
-    step before KV block kb's first needed row-block names that block;
-    capped at the last row-block for a KV block no row needs (Sk > Sq)."""
+    step before KV block kb's first needed row-block names that block,
+    and with a ``window`` a step past its last needed one (the band's
+    edge) names that one; capped at the last row-block for a KV block no
+    row needs (Sk > Sq)."""
     if not causal:
         return lambda kb, qb: qb
-    return lambda kb, qb: jnp.minimum(jnp.maximum(qb, (kb * bk) // bq),
-                                      n_q - 1)
+    if window is None:
+        return lambda kb, qb: jnp.minimum(jnp.maximum(qb, (kb * bk) // bq),
+                                          n_q - 1)
+    return lambda kb, qb: jnp.minimum(
+        jnp.clip(qb, (kb * bk) // bq, (kb * bk + bk + window - 2) // bq),
+        n_q - 1)
+
+
+def _mask(mask, qb, kb, *, causal: bool, bq: int, bk: int, window):
+    """The key mask of a (bq, bk) tile with the causal edge and, with a
+    ``window``, the band's lower edge: q sees k iff 0 <= q - k < window."""
+    if not causal:
+        return mask
+    q_pos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    mask = mask & (q_pos >= k_pos)
+    if window is not None:
+        mask = mask & (q_pos - k_pos < window)
+    return mask
 
 
 # ---------------------------------------------------------------------------
@@ -90,7 +124,8 @@ def _q_index(causal: bool, bq: int, bk: int, n_q: int):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref, probe_ref,
                 m_ref, l_ref, acc_ref, *,
-                scale: float, causal: bool, bq: int, bk: int, n_k: int):
+                scale: float, causal: bool, bq: int, bk: int, n_k: int,
+                window):
     qb = pl.program_id(1)
     kb = pl.program_id(2)
 
@@ -106,11 +141,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref, probe_ref,
         k = k_ref[0]                       # (bk, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = kvm_ref[0] != 0             # (1, bk)
-        if causal:
-            q_pos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = mask & (q_pos >= k_pos)
+        mask = _mask(kvm_ref[0] != 0, qb, kb, causal=causal, bq=bq, bk=bk,
+                     window=window)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[...]
@@ -129,7 +161,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref, probe_ref,
         probe_ref[...] += 1
 
     if causal:
-        pl.when(_causal_need(qb, kb, bq, bk))(_work)
+        pl.when(_causal_need(qb, kb, bq, bk, window))(_work)
         kb_last = jnp.minimum(n_k - 1, (qb * bq + bq - 1) // bk)
     else:
         _work()
@@ -146,7 +178,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvm_ref, o_ref, lse_ref, probe_ref,
 
 
 def _fwd_call(qf, kf, vf, kvm, *, causal: bool, bq: int, bk: int,
-              interpret: bool):
+              interpret: bool, window=None):
     """Padded flat call: qf (G,Sq,D), kf/vf (G,Sk,D), kvm (G,1,Sk) int32.
     Returns (out (G,Sq,D), lse (G,Sq,1) f32, probe (G,n_q,1,1) int32).
 
@@ -159,10 +191,10 @@ def _fwd_call(qf, kf, vf, kvm, *, causal: bool, bq: int, bk: int,
     sk = kf.shape[1]
     n_q, n_k = sq // bq, sk // bk
     scale = 1.0 / math.sqrt(d)
-    kv = _kv_index(causal, bq, bk)
+    kv = _kv_index(causal, bq, bk, window)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, n_k=n_k),
+                          bq=bq, bk=bk, n_k=n_k, window=window),
         grid=(g, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda gi, qb, kb: (gi, qb, 0)),
@@ -195,16 +227,13 @@ def _fwd_call(qf, kf, vf, kvm, *, causal: bool, bq: int, bk: int,
 
 
 def _recompute_p(q_ref, k_ref, kvm_ref, lse_ref, qb, kb, *,
-                 scale: float, causal: bool, bq: int, bk: int):
+                 scale: float, causal: bool, bq: int, bk: int, window):
     """Rebuild the (bq, bk) probability block from q, k and the saved lse.
     Masked positions and dead rows come back exactly 0."""
     s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    mask = kvm_ref[0] != 0                 # (1, bk)
-    if causal:
-        q_pos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = mask & (q_pos >= k_pos)
+    mask = _mask(kvm_ref[0] != 0, qb, kb, causal=causal, bq=bq, bk=bk,
+                 window=window)
     lse = lse_ref[0]                       # (bq, 1)
     lse_safe = jnp.where(lse > _DEAD_ROW, lse, 0.0)
     return jnp.where(mask, jnp.exp(s - lse_safe), 0.0)
@@ -212,7 +241,8 @@ def _recompute_p(q_ref, k_ref, kvm_ref, lse_ref, qb, kb, *,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *,
-                   scale: float, causal: bool, bq: int, bk: int, n_k: int):
+                   scale: float, causal: bool, bq: int, bk: int, n_k: int,
+                   window):
     qb = pl.program_id(1)
     kb = pl.program_id(2)
 
@@ -222,7 +252,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
 
     def _work():
         p = _recompute_p(q_ref, k_ref, kvm_ref, lse_ref, qb, kb,
-                         scale=scale, causal=causal, bq=bq, bk=bk)
+                         scale=scale, causal=causal, bq=bq, bk=bk,
+                         window=window)
         do = do_ref[0]
         dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -232,7 +263,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
 
     if causal:
-        pl.when(_causal_need(qb, kb, bq, bk))(_work)
+        pl.when(_causal_need(qb, kb, bq, bk, window))(_work)
     else:
         _work()
 
@@ -243,7 +274,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale: float, causal: bool, bq: int, bk: int, n_q: int):
+                    scale: float, causal: bool, bq: int, bk: int, n_q: int,
+                    window):
     kb = pl.program_id(1)
     qb = pl.program_id(2)
 
@@ -254,7 +286,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
 
     def _work():
         p = _recompute_p(q_ref, k_ref, kvm_ref, lse_ref, qb, kb,
-                         scale=scale, causal=causal, bq=bq, bk=bk)
+                         scale=scale, causal=causal, bq=bq, bk=bk,
+                         window=window)
         do = do_ref[0]
         dv_acc[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -267,7 +300,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
 
     if causal:
-        pl.when(_causal_need(qb, kb, bq, bk))(_work)
+        pl.when(_causal_need(qb, kb, bq, bk, window))(_work)
     else:
         _work()
 
@@ -282,20 +315,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, kvm_ref, do_ref, lse_ref, delta_ref,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_core(qf, kf, vf, kvm, causal, bq, bk, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_core(qf, kf, vf, kvm, causal, bq, bk, interpret, window):
     out, _, _ = _fwd_call(qf, kf, vf, kvm, causal=causal, bq=bq, bk=bk,
-                          interpret=interpret)
+                          interpret=interpret, window=window)
     return out
 
 
-def _flash_core_fwd(qf, kf, vf, kvm, causal, bq, bk, interpret):
+def _flash_core_fwd(qf, kf, vf, kvm, causal, bq, bk, interpret, window):
     out, lse, _ = _fwd_call(qf, kf, vf, kvm, causal=causal, bq=bq, bk=bk,
-                            interpret=interpret)
+                            interpret=interpret, window=window)
     return out, (qf, kf, vf, kvm, out, lse)
 
 
-def _flash_core_bwd(causal, bq, bk, interpret, res, dout):
+def _flash_core_bwd(causal, bq, bk, interpret, window, res, dout):
     qf, kf, vf, kvm, out, lse = res
     g, sq, d = qf.shape
     sk = kf.shape[1]
@@ -304,8 +337,8 @@ def _flash_core_bwd(causal, bq, bk, interpret, res, dout):
     # D_i = sum_j dO_ij * O_ij, shared by both backward kernels
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
-    common = dict(scale=scale, causal=causal, bq=bq, bk=bk)
-    kv = _kv_index(causal, bq, bk)
+    common = dict(scale=scale, causal=causal, bq=bq, bk=bk, window=window)
+    kv = _kv_index(causal, bq, bk, window)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, n_k=n_k, **common),
         grid=(g, n_q, n_k),
@@ -323,7 +356,7 @@ def _flash_core_bwd(causal, bq, bk, interpret, res, dout):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
     )(qf, kf, vf, kvm, dout, lse, delta)
-    qi = _q_index(causal, bq, bk, n_q)
+    qi = _q_index(causal, bq, bk, n_q, window)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, n_q=n_q, **common),
         grid=(g, n_k, n_q),
@@ -359,7 +392,11 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 # ---------------------------------------------------------------------------
 
 
-def _validate(q, k, v, kv_valid):
+def _validate(q, k, v, kv_valid, causal=True, window=None):
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"flash_attention: window={window} needs causal=True and a "
+            f"window of at least 1")
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError(
             f"flash_attention expects rank-4 (B,H,S,D) operands, got "
@@ -441,15 +478,18 @@ def _prepare(q, k, v, kv_valid, bq, bk):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("causal", "bq", "bk", "interpret"))
-def _flash_padded(q, k, v, kv_valid, *, causal, bq, bk, interpret):
+                   static_argnames=("causal", "bq", "bk", "interpret",
+                                    "window"))
+def _flash_padded(q, k, v, kv_valid, *, causal, bq, bk, interpret,
+                  window=None):
     b, h, sq, d = q.shape
     qf, kf, vf, kvm, bq, bk = _prepare(q, k, v, kv_valid, bq, bk)
-    out = _flash_core(qf, kf, vf, kvm, causal, bq, bk, interpret)
+    out = _flash_core(qf, kf, vf, kvm, causal, bq, bk, interpret, window)
     return out[:, :sq].reshape(b, h, sq, d)
 
 
 def flash_attention(q, k, v, *, kv_valid=None, causal: bool = True,
+                    window: int | None = None,
                     bq: int | None = None, bk: int | None = None,
                     interpret: bool = False):
     """Blockwise attention with a training-grade VJP.
@@ -458,13 +498,16 @@ def flash_attention(q, k, v, *, kv_valid=None, causal: bool = True,
     (B,H,Sq,D). Differentiable w.r.t. q, k, v. Ragged Sq/Sk are padded to
     block multiples internally; rows with no valid key return zeros.
     bq/bk None take ``default_block`` of Sq/Sk and the head row's bytes.
+    ``window`` (causal only) keeps the band 0 <= q - k < window: blocks
+    outside it are neither computed nor fetched.
     """
-    _validate(q, k, v, kv_valid)
+    _validate(q, k, v, kv_valid, causal, window)
     return _flash_padded(q, k, v, kv_valid, causal=causal, bq=bq, bk=bk,
-                         interpret=interpret)
+                         interpret=interpret, window=window)
 
 
 def flash_attention_probe(q, k, v, *, kv_valid=None, causal: bool = True,
+                          window: int | None = None,
                           bq: int | None = None, bk: int | None = None,
                           interpret: bool = False):
     """Forward pass plus the block-skip witness.
@@ -474,9 +517,9 @@ def flash_attention_probe(q, k, v, *, kv_valid=None, causal: bool = True,
     The causal guarantee is ``probe[g, qb] == min(n_k, qb*bq//bk + 1)``
     rather than n_k — O(n_k/2) summed over the triangle.
     """
-    _validate(q, k, v, kv_valid)
+    _validate(q, k, v, kv_valid, causal, window)
     b, h, sq, d = q.shape
     qf, kf, vf, kvm, bq, bk = _prepare(q, k, v, kv_valid, bq, bk)
     out, _, probe = _fwd_call(qf, kf, vf, kvm, causal=causal, bq=bq, bk=bk,
-                              interpret=interpret)
+                              interpret=interpret, window=window)
     return out[:, :sq].reshape(b, h, sq, d), probe.reshape(probe.shape[:2])

@@ -28,19 +28,24 @@ def conv2d_ref(x, w):
     return out[0].astype(x.dtype)
 
 
-def flash_attention_ref(q, k, v, *, causal: bool = True, kv_valid=None):
+def flash_attention_ref(q, k, v, *, causal: bool = True, kv_valid=None,
+                        window=None):
     """q (B,H,Sq,D); k,v (B,H,Sk,D); kv_valid (B,Sk) bool or None.
 
     Pins the kernel's conventions: causal masking compares raw row/column
-    indices, and rows with NO valid key output ZEROS (never the uniform
-    softmax garbage a -1e30 fill produces)."""
+    indices (with a ``window``, the band ``0 <= q - k < window``), and rows
+    with NO valid key output ZEROS (never the uniform softmax garbage a
+    -1e30 fill produces)."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     sq, sk = q.shape[2], k.shape[2]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     mask = jnp.ones((q.shape[0], 1, sq, sk), bool)
     if causal:
-        mask = mask & (jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :])
+        rel = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        mask = mask & (rel >= 0)
+        if window is not None:
+            mask = mask & (rel < window)
     if kv_valid is not None:
         mask = mask & kv_valid[:, None, None, :]
     s = jnp.where(mask, s, -1e30)

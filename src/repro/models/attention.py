@@ -90,7 +90,7 @@ def checkpoint_policy(name: str):
             f"{['none', *_CKPT_POLICIES]}") from None
 
 
-def _flash_attention(q, k, v, kv_valid, causal: bool):
+def _flash_attention(q, k, v, kv_valid, causal: bool, window=None):
     """(B,S,H,D)-layout adapter around kernels.ops.flash_attention.
 
     Under a mesh the kernel runs per lane-local shard (batch on the data
@@ -101,7 +101,8 @@ def _flash_attention(q, k, v, kv_valid, causal: bool):
     def local(q, k, v, kv_valid):
         out = kops.flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), kv_valid=kv_valid, causal=causal)
+            v.transpose(0, 2, 1, 3), kv_valid=kv_valid, causal=causal,
+            window=window)
         return out.transpose(0, 2, 1, 3)
 
     ctx = _MESH_CTX
@@ -186,12 +187,13 @@ def _masked_softmax_attn(q, k, v, mask):
 
 def chunked_attention(q, k, v, q_pos, kv_valid, kv_offset=0, chunk=KV_CHUNK,
                       triangular=False, threshold=None, use_flash="auto",
-                      block_remat="none"):
+                      block_remat="none", window=None):
     """Blockwise online-softmax attention over KV chunks.
 
     q: (B,S,H,D); k,v: (B,T,H,D); q_pos: (B,S) absolute positions;
     kv_valid: (B,T) bool; kv positions are kv_offset + arange(T).
-    Causal: kv_pos <= q_pos AND kv_valid.
+    Causal: kv_pos <= q_pos AND kv_valid; with a ``window`` also
+    q_pos - kv_pos < window (a sliding-window layer).
 
     ``triangular=True`` (training: S==T, q_pos==arange, kv_offset==0)
     splits queries into blocks and runs each block only against its causal
@@ -216,16 +218,25 @@ def chunked_attention(q, k, v, q_pos, kv_valid, kv_offset=0, chunk=KV_CHUNK,
     if tri and flash_route_enabled(use_flash) and _flash_fits_mesh(b, h):
         # q_pos is arange(S) by the triangular contract, so the kernel's
         # index-vs-index causal mask is exactly this mask
-        return _flash_attention(q, k, v, kv_valid, causal=True)
+        return _flash_attention(q, k, v, kv_valid, causal=True,
+                                window=window)
+
+    def visible(kv_pos):
+        """(B,1,S,T) causal (and band) mask of q_pos against kv_pos."""
+        mask = kv_pos[None, None, None, :] <= q_pos[:, None, :, None]
+        if window is not None:
+            mask = mask & (q_pos[:, None, :, None]
+                           - kv_pos[None, None, None, :] < window)
+        return mask
 
     if t_len <= max(chunk, threshold):
-        mask = (kv_pos[None, None, None, :] <= q_pos[:, None, :, None]) \
-            & kv_valid[:, None, None, :]
+        mask = visible(kv_pos) & kv_valid[:, None, None, :]
         return _masked_softmax_attn(q, k, v, mask)
 
     if tri and s_len % chunk == 0:
         blk = functools.partial(chunked_attention, kv_offset=kv_offset,
-                                chunk=chunk, threshold=threshold)
+                                chunk=chunk, threshold=threshold,
+                                window=window)
         policy = checkpoint_policy(block_remat)
         if block_remat not in (None, "none", ""):
             blk = jax.checkpoint(blk, policy=policy)
@@ -260,8 +271,7 @@ def chunked_attention(q, k, v, q_pos, kv_valid, kv_offset=0, chunk=KV_CHUNK,
         posb = jax.lax.dynamic_slice_in_dim(kv_pos, start, chunk, axis=0)
         sc = jnp.einsum("bshd,bthd->bhst", q, kb,
                         preferred_element_type=jnp.float32) * scale
-        mask = (posb[None, None, None, :] <= q_pos[:, None, :, None]) \
-            & validb[:, None, None, :]
+        mask = visible(posb) & validb[:, None, None, :]
         sc = jnp.where(mask, sc, NEG_INF)
         m_new = jnp.maximum(m_run, sc.max(axis=-1))
         alpha = jnp.exp(m_run - m_new)
@@ -307,8 +317,10 @@ def update_cache(cache_k, cache_v, k_new, v_new, lengths):
 
 def gqa_attention(cfg: ArchConfig, p: dict, x, positions, *,
                   cache: Optional[dict] = None, kv_valid=None, causal=True,
-                  prefill_from_zero=False):
+                  prefill_from_zero=False, window=None, yarn=None):
     """x (B,S,d); positions (B,S) absolute. cache = {"k","v","lengths"} or None.
+    ``window``: a sliding-window layer's width; ``yarn``: YaRN rotary
+    scaling (a full layer's, ``cfg.yarn``).
 
     Returns (out (B,S,d), new_cache_entries or None).
     """
@@ -317,7 +329,7 @@ def gqa_attention(cfg: ArchConfig, p: dict, x, positions, *,
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(x.dtype))
 
-    cos, sin = rotary_embedding(positions, hd, cfg.rope_theta)
+    cos, sin = rotary_embedding(positions, hd, cfg.rope_theta, yarn)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
 
@@ -347,7 +359,8 @@ def gqa_attention(cfg: ArchConfig, p: dict, x, positions, *,
                             or None,
                             use_flash=getattr(cfg, "attn_flash", "auto"),
                             block_remat=getattr(cfg, "attn_block_remat",
-                                                "none"))
+                                                "none"),
+                            window=window)
     out = _mask_pad_heads(cfg, out)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return out, new_cache

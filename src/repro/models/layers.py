@@ -166,12 +166,43 @@ def activation_fn(name: str):
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu, "relu": jax.nn.relu}[name]
 
 
-def rotary_embedding(positions, head_dim, theta):
-    """positions (...,) int -> cos/sin (..., head_dim/2)."""
+def rotary_embedding(positions, head_dim, theta, yarn=None):
+    """positions (...,) int -> cos/sin (..., head_dim/2); ``yarn`` (a
+    ``configs.base.YarnConfig``) scales the frequencies and the tables."""
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    scale = 1.0
+    if yarn is not None:
+        freqs = freqs * _yarn_freq_scale(yarn, head_dim, theta)
+        scale = yarn.attention_factor
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    return cos, sin
+
+
+def _yarn_freq_scale(yarn, head_dim, theta):
+    """Per-frequency factor of YaRN: 1/factor (interpolated) below the
+    correction range, 1 (extrapolated) above it, a linear ramp between;
+    the range is where a dimension turns ``beta_fast`` .. ``beta_slow``
+    times within the original context, floored and ceiled."""
+    half = head_dim // 2
+
+    def dim_of(rotations):
+        return head_dim * math.log(yarn.original_max_position_embeddings
+                                   / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0.0, 1.0)
+    extrapolated = 1.0 - ramp
+    return jnp.asarray(extrapolated + (1.0 - extrapolated) / yarn.factor,
+                       jnp.float32)
 
 
 def apply_rope(x, cos, sin):

@@ -33,7 +33,7 @@ from repro.models.attention import (checkpoint_policy as attn_checkpoint_policy,
                                     gqa_template, mla_attention, mla_template)
 from repro.models.layers import P, rms_norm
 from repro.models.mlp import mlp, mlp_template
-from repro.models.moe import moe_block, moe_template
+from repro.models.moe import COUNTERS, moe_block, moe_template, uses_ep
 from repro.models.sharding import MeshCtx
 
 # ---------------------------------------------------------------------------
@@ -159,7 +159,8 @@ def set_prefill_hint(value: bool):
     _PREFILL_FROM_ZERO = value
 
 
-def _attn_block(cfg, p, x, positions, cache=None, causal=True):
+def _attn_block(cfg, p, x, positions, cache=None, causal=True, window=None,
+                yarn=None):
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.use_mla:
         a, new_cache = mla_attention(cfg, p["attn"], h, positions,
@@ -168,7 +169,8 @@ def _attn_block(cfg, p, x, positions, cache=None, causal=True):
     else:
         a, new_cache = gqa_attention(cfg, p["attn"], h, positions,
                                      cache=cache, causal=causal,
-                                     prefill_from_zero=_PREFILL_FROM_ZERO)
+                                     prefill_from_zero=_PREFILL_FROM_ZERO,
+                                     window=window, yarn=yarn)
     return a, h, new_cache
 
 
@@ -188,14 +190,24 @@ def dense_block(cfg, p, x, positions, cache=None, causal=True, memory=None):
     return x, new_cache
 
 
-def moe_layer(cfg, p, x, positions, ctx, cache=None):
-    with jax.named_scope("attn"):
-        a, _, new_cache = _attn_block(cfg, p, x, positions, cache)
+def moe_layer(cfg, p, x, positions, ctx, cache=None, kind="full"):
+    """One MoE layer; returns (x, {"aux", counters...}, new_cache). A
+    ``kind`` "sliding" layer attends within ``cfg.sliding_window`` with
+    plain RoPE, a full one with ``cfg.yarn``'s; the attention scope names
+    the kind (``attn.window`` / ``attn.full``) when the config has kinds."""
+    sliding = kind == "sliding"
+    scope = ("attn." + ("window" if sliding else "full")) \
+        if cfg.layer_kinds else "attn"
+    with jax.named_scope(scope):
+        a, _, new_cache = _attn_block(
+            cfg, p, x, positions, cache,
+            window=cfg.sliding_window if sliding else None,
+            yarn=None if sliding else cfg.yarn)
     x = x + a
     with jax.named_scope("mlp"):
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        y, aux = moe_block(cfg, p["moe"], h2, ctx)
-    return x + y, aux, new_cache
+        y, aux, counters = moe_block(cfg, p["moe"], h2, ctx)
+    return x + y, dict(counters, aux=aux), new_cache
 
 
 def mix_layer(cfg, p, x, state=None):
@@ -213,26 +225,31 @@ def mix_layer(cfg, p, x, state=None):
 # ---------------------------------------------------------------------------
 
 
-def _scan_layers(cfg, stacked_params, body, x, cache_xs=None):
+def _scan_layers(cfg, stacked_params, body, x, cache_xs=None, aux0=None,
+                 remat=True):
     """Scan ``body`` over stacked layer params (+ optional stacked cache).
 
-    body(params_i, x, cache_i) -> (x, new_cache_i, aux_i)
-    Returns (x, new_cache_stacked, aux_sum).
+    body(params_i, x, cache_i) -> (x, new_cache_i, aux_i); ``aux0`` is
+    the zero of the aux tree (a float32 scalar when None), summed leaf by
+    leaf. ``remat=False`` leaves the body as it is (it rematerializes its
+    own parts). Returns (x, new_cache_stacked, aux_sum).
     """
+    aux0 = jnp.float32(0.0) if aux0 is None else aux0
+
     def scan_fn(carry, xs):
         x, aux = carry
         p_i, c_i = xs
         x, new_c, a = body(p_i, x, c_i)
-        return (x, aux + a), new_c
+        return (x, jax.tree_util.tree_map(jnp.add, aux, a)), new_c
 
-    fn = _maybe_remat(scan_fn, cfg)
+    fn = _maybe_remat(scan_fn, cfg) if remat else scan_fn
     if cfg.scan_layers:
         (x, aux), new_cache = jax.lax.scan(
-            fn, (x, jnp.float32(0.0)), (stacked_params, cache_xs))
+            fn, (x, aux0), (stacked_params, cache_xs))
         return x, new_cache, aux
     # unrolled (smoke tests): index the stacked params
     n = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
-    aux = jnp.float32(0.0)
+    aux = aux0
     new_caches = []
     for i in range(n):
         p_i = jax.tree_util.tree_map(lambda a: a[i], stacked_params)
@@ -246,6 +263,41 @@ def _scan_layers(cfg, stacked_params, body, x, cache_xs=None):
     else:
         new_cache = None
     return x, new_cache, aux
+
+
+def _scan_periods(cfg, stacked_params, kinds, layer, x, cache_xs, aux0):
+    """Scan over periods of ``len(kinds)`` layers: one compiled body holds
+    a whole period, each layer of it ``layer(p_i, x, c_i, kind=...)``
+    rematerialized on its own, so the backward pass rebuilds one layer's
+    activations at a time. Same contract as ``_scan_layers``."""
+    period = len(kinds)
+
+    def split(a):
+        return a.reshape((a.shape[0] // period, period) + a.shape[1:])
+
+    steps = [_maybe_remat(functools.partial(layer, kind=kind), cfg)
+             for kind in kinds]
+
+    def body(p_g, x, c_g):
+        aux, new_c = aux0, []
+        for j, step in enumerate(steps):
+            pick = functools.partial(jax.tree_util.tree_map,
+                                     lambda a: a[j])
+            x, nc, a = step(pick(p_g), x, None if c_g is None else pick(c_g))
+            aux = jax.tree_util.tree_map(jnp.add, aux, a)
+            new_c.append(nc)
+        if new_c[0] is None:
+            return x, None, aux
+        return x, jax.tree_util.tree_map(lambda *c: jnp.stack(c), *new_c), aux
+
+    x, nc, aux = _scan_layers(
+        cfg, jax.tree_util.tree_map(split, stacked_params), body, x,
+        None if cache_xs is None else jax.tree_util.tree_map(split, cache_xs),
+        aux0, remat=False)
+    if nc is not None:
+        nc = jax.tree_util.tree_map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), nc)
+    return x, nc, aux
 
 
 def forward(cfg: ArchConfig, params: dict, tokens, *,
@@ -335,12 +387,27 @@ def forward(cfg: ArchConfig, params: dict, tokens, *,
             c_xs = _moe_cache_xs(cache, "dense_", kv_keys, n_dense, lengths, b)
             x, nc_d, _ = _scan_layers(cfg, params["dense_layers"], dbody, x, c_xs)
         n_moe = cfg.n_layers - n_dense
+        kinds = cfg.layer_kinds or ("full",)
+        counters = () if uses_ep(cfg, b * s, ctx) else COUNTERS
+        aux0 = {k: jnp.float32(0.0) for k in ("aux",) + counters}
 
-        def mbody(p_i, x, c_i):
-            x, a, nc = moe_layer(cfg, p_i, x, positions, ctx, cache=c_i)
+        def mbody(p_i, x, c_i, kind=kinds[0]):
+            x, a, nc = moe_layer(cfg, p_i, x, positions, ctx, cache=c_i,
+                                 kind=kind)
             return x, nc, a
         c_xs = _moe_cache_xs(cache, "", kv_keys, n_moe, lengths, b)
-        x, nc_m, aux = _scan_layers(cfg, params["layers"], mbody, x, c_xs)
+        if len(kinds) == 1:
+            x, nc_m, aux = _scan_layers(cfg, params["layers"], mbody, x,
+                                        c_xs, aux0)
+        else:
+            x, nc_m, aux = _scan_periods(cfg, params["layers"], kinds, mbody,
+                                         x, c_xs, aux0)
+        extras["moe"] = dict(aux)
+        aux = extras["moe"].pop("aux")
+        if counters:
+            # per step: pairs and drops summed over the layers, the load
+            # ratio averaged over them
+            extras["moe"]["moe_load_max_over_mean"] /= n_moe
         if cache is not None:
             new_cache = {k: nc_m[k] for k in kv_keys}
             if n_dense:
@@ -504,7 +571,7 @@ def lm_loss(cfg: ArchConfig, params, batch, ctx: Optional[MeshCtx] = None):
                                   frontend_emb=batch.get("frontend_emb"))
     loss = _ce(logits, batch["labels"])
     total = loss + 0.01 * aux
-    metrics = {"ce": loss, "aux": aux}
+    metrics = {"ce": loss, "aux": aux, **extras.get("moe", {})}
     if cfg.mtp_depth and "mtp" in params:
         mtp_loss = _mtp_loss(cfg, params, batch, extras["final_hidden"])
         total = total + 0.3 * mtp_loss

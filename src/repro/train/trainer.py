@@ -15,6 +15,7 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import ckpt
 from repro.configs.base import ArchConfig
 from repro.core.stripmine import fuse_steps as _fuse
@@ -57,12 +58,25 @@ class Trainer:
             st_sh = step_lib.named_for(self.bundle.state_specs,
                                        self.bundle.abstract_state, mesh)
             self.state_sharding = st_sh
-            self.step_fn = jax.jit(self.bundle.step_fn,
-                                   in_shardings=(st_sh, None),
-                                   out_shardings=(st_sh, None))
+            self.jit_step = jax.jit(self.bundle.step_fn,
+                                    in_shardings=(st_sh, None),
+                                    out_shardings=(st_sh, None))
         else:
             self.state_sharding = None
-            self.step_fn = jax.jit(self.bundle.step_fn)
+            self.jit_step = jax.jit(self.bundle.step_fn)
+
+    def step_fn(self, state, batch):
+        """One step of ``jit_step``. A step of an MoE model files the pairs
+        its expert layers computed (the ``moe_held_pairs`` metric, a
+        device array, so no sync) on an ``obs`` span
+        ``train.step.dispatch``, for readers that sum them over the steps
+        of a window."""
+        t0 = time.perf_counter()
+        state, metrics = self.jit_step(state, batch)
+        if "moe_held_pairs" in metrics:
+            obs.record("train.step.dispatch", t0, time.perf_counter(),
+                       moe_held_pairs=metrics["moe_held_pairs"])
+        return state, metrics
 
     # -- state ------------------------------------------------------------
 
@@ -88,7 +102,8 @@ class Trainer:
     def run(self, on_step: Optional[Callable] = None):
         start, state = self.restore_or_init()
         t = self.tcfg
-        fused = _fuse(self.step_fn, t.fuse_steps) if t.fuse_steps > 1 else None
+        fused = _fuse(self.jit_step, t.fuse_steps) if t.fuse_steps > 1 \
+            else None
         step = start
         pending_save = None
         while step < t.steps:
@@ -96,7 +111,7 @@ class Trainer:
             if fused is not None:
                 k = min(t.fuse_steps, t.steps - step)
                 if k < t.fuse_steps:
-                    fused = _fuse(self.step_fn, k)
+                    fused = _fuse(self.jit_step, k)
                 batches = [self.source.batch(step + i) for i in range(k)]
                 stacked = jax.tree_util.tree_map(
                     lambda *xs: np.stack(xs), *batches)

@@ -6,7 +6,8 @@ routing in models/attention.py:
 - custom-VJP backward vs the jnp oracle's jax.grad across causal x dtype
   x ragged lengths (tol 1e-5 fp32 / 2e-2 bf16),
 - the causal block-skip probe (fully masked KV blocks issue no work) and
-  the index maps that fetch nothing for a skipped block,
+  the index maps that fetch nothing for a skipped block, with and
+  without a sliding window's lower edge,
 - the block rule for callers that name no block shape,
 - internal pad-to-block-multiple instead of the old bare assert, with
   ValueError naming the shapes for genuinely unsupported inputs,
@@ -203,6 +204,79 @@ def test_causal_index_maps_fetch_only_live_blocks(sq, sk, bq, bk):
         assert all(0 <= i < n_q for i in idx)
         assert all(idx[qb] == qb for qb in range(n_q) if live[qb][kb])
         assert _fetches(idx) == max(1, sum(row[kb] for row in live))
+
+
+@pytest.mark.parametrize("window", [128, 1024])
+def test_window_default_blocks_match_ref(window, rng):
+    """Forward and grads of a sliding-window layer (q sees k iff
+    0 <= q - k < window) at the blocks the kernel picks for 2048 keys."""
+    b, h, s, d = 1, 2, 2048, 16
+    q, k, v = (_mk(rng, (b, h, s, d), jnp.float32) for _ in range(3))
+
+    def run(fn, **kw):
+        def loss(q, k, v):
+            o = fn(q, k, v, causal=True, window=window, **kw)
+            return jnp.sum(jnp.sin(o)), o
+        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+        return o, g
+
+    o_k, g_k = run(ops.flash_attention, interpret=True)
+    o_r, g_r = run(ref.flash_attention_ref)
+    causal = ref.flash_attention_ref(q, k, v, causal=True)
+    assert float(jnp.abs(o_r - causal).max()) > 1e-3   # the band bites
+    tol = GRAD_TOL[jnp.float32]
+    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r),
+                               rtol=tol, atol=tol * 4)
+    for name, a, b_ in zip("qkv", g_k, g_r):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=tol, atol=tol * 4,
+                                   err_msg=f"d{name}")
+
+
+def test_window_skip_counts_live_blocks(rng):
+    """8192 keys in 1024 blocks with a 1024 window: each q row-block
+    visits its diagonal block and the one before it, 15 of the grid's 64
+    (36 causal)."""
+    q = _mk(rng, (1, 1, 8192, 128), jnp.float32)
+    _, probe = flash_attention_probe(q, q, q, causal=True, window=1024,
+                                     interpret=True)
+    assert K.default_block(8192, 128 * 4) == 1024
+    assert np.asarray(probe).reshape(-1).tolist() == [1] + [2] * 7
+    assert int(probe.sum()) == 15
+
+
+@pytest.mark.parametrize("s,bq,bk,window", [
+    (1024, 128, 128, 128), (1024, 128, 128, 300), (1024, 256, 128, 200),
+    (1024, 128, 256, 129), (1024, 128, 128, 2048),
+])
+def test_window_index_maps_fetch_only_live_blocks(s, bq, bk, window):
+    """The window's lower edge in the index maps: a step below the band
+    names the first live block, so both grids fetch each live block once
+    and nothing else, and every live step names its own block."""
+    n_q, n_k = s // bq, s // bk
+    kv = K._kv_index(True, bq, bk, window)
+    qi = K._q_index(True, bq, bk, n_q, window)
+    live = [[bool(K._causal_need(qb, kb, bq, bk, window))
+             for kb in range(n_k)] for qb in range(n_q)]
+    rel = np.arange(s)[:, None] - np.arange(s)[None, :]
+    band = ((rel >= 0) & (rel < window)).reshape(n_q, bq, n_k, bk)
+    assert live == band.any(axis=(1, 3)).tolist()
+    for qb in range(n_q):
+        idx = [int(kv(qb, kb)) for kb in range(n_k)]
+        assert all(idx[kb] == kb for kb in range(n_k) if live[qb][kb])
+        assert _fetches(idx) == sum(live[qb])
+    for kb in range(n_k):
+        idx = [int(qi(kb, qb)) for qb in range(n_q)]
+        assert all(0 <= i < n_q for i in idx)
+        assert all(idx[qb] == qb for qb in range(n_q) if live[qb][kb])
+        assert _fetches(idx) == max(1, sum(row[kb] for row in live))
+
+
+def test_window_needs_causal(rng):
+    q = _mk(rng, (1, 1, 16, 8), jnp.float32)
+    with pytest.raises(ValueError, match="window"):
+        ops.flash_attention(q, q, q, causal=False, window=4, interpret=True)
 
 
 def test_non_causal_runs_full_grid(rng):

@@ -1,80 +1,38 @@
-"""MoE dispatch: dense one-hot path properties + EP shard_map path vs the
-dense oracle (subprocess with fake devices)."""
+"""MoE dispatch: the EP shard_map path against the held-expert path
+(subprocess with fake devices), and the router. The held-expert path's
+own tests are in ``test_moe_held.py``."""
 import dataclasses
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs import get_config, reduced
 from repro.models.layers import init_params
-from repro.models.moe import moe_dense_dispatch, moe_template, _route
+from repro.models.moe import moe_template, _route
 from conftest import run_devices
 
 
-def _moe_cfg(cf=8.0, n_experts=8, top_k=2, d=32, ff=16):
+def _moe_cfg(n_experts=8, top_k=2, d=32, ff=16):
     cfg = reduced(get_config("deepseek-v3-671b"), mtp_depth=0)
     return dataclasses.replace(
         cfg, d_model=d,
         moe=dataclasses.replace(cfg.moe, n_experts=n_experts, top_k=top_k,
-                                expert_d_ff=ff, capacity_factor=cf,
-                                n_shared_experts=0, n_dense_layers=0))
+                                expert_d_ff=ff, n_shared_experts=0,
+                                n_dense_layers=0))
 
 
 def _params(cfg, seed=0):
     return init_params(moe_template(cfg), jax.random.PRNGKey(seed))
 
 
-def test_dense_dispatch_no_drop_is_exact():
-    """With huge capacity, dispatch+combine == explicit per-token expert mix."""
-    cfg = _moe_cfg(cf=16.0)
-    p = _params(cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (24, cfg.d_model))
-    y, aux = moe_dense_dispatch(cfg, p, x)
-    gates, ids, _ = _route(x, p["router"], cfg.moe.top_k, cfg.moe.n_experts)
-    # explicit oracle
-    def expert(e, t):
-        h = jax.nn.silu(x[t] @ p["w_gate"][e]) * (x[t] @ p["w_up"][e])
-        return h @ p["w_down"][e]
-    want = np.zeros_like(np.asarray(y))
-    for t in range(24):
-        for j in range(cfg.moe.top_k):
-            want[t] += float(gates[t, j]) * np.asarray(
-                expert(int(ids[t, j]), t))
-    np.testing.assert_allclose(np.asarray(y), want, rtol=2e-4, atol=2e-4)
-    assert float(aux) > 0
-
-
-def test_capacity_drops_reduce_output():
-    cfg_small = _moe_cfg(cf=0.25)
-    cfg_big = _moe_cfg(cf=16.0)
-    p = _params(cfg_small)
-    x = jax.random.normal(jax.random.PRNGKey(1), (64, cfg_small.d_model))
-    y_small, _ = moe_dense_dispatch(cfg_small, p, x)
-    y_big, _ = moe_dense_dispatch(cfg_big, p, x)
-    # dropped tokens produce zero contribution -> smaller norm
-    assert float(jnp.linalg.norm(y_small)) < float(jnp.linalg.norm(y_big))
-
-
-def test_grouped_equals_ungrouped():
-    cfg = _moe_cfg(cf=16.0)
-    p = _params(cfg)
-    x = jax.random.normal(jax.random.PRNGKey(2), (64, cfg.d_model))
-    y1, _ = moe_dense_dispatch(cfg, p, x, group_size=64)
-    y2, _ = moe_dense_dispatch(cfg, p, x, group_size=16)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
-                               rtol=2e-4, atol=2e-4)
-
-
 def test_ep_shard_map_matches_dense():
-    """EP all_to_all path == dense one-hot oracle on an 8-device mesh."""
+    """EP all_to_all path == the held-expert path (every expert held) on
+    an 8-device mesh."""
     code = """
 import dataclasses, jax, numpy as np, jax.numpy as jnp
 from repro.configs import get_config, reduced
 from repro.models.layers import init_params
-from repro.models.moe import (moe_dense_dispatch, moe_ep_shard_map,
-                              moe_template)
+from repro.models.moe import moe_ep_shard_map, moe_held, moe_template
 import repro.models.moe as moe_mod
 from repro.models.sharding import MeshCtx
 from repro.launch.mesh import make_mesh
@@ -91,7 +49,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model))
 moe_mod.EP_CHUNK_TOKENS = 4   # force strip-mining through multiple chunks
 with mesh:
     y_ep, aux_ep = jax.jit(lambda xx: moe_ep_shard_map(cfg, p, xx, ctx))(x)
-y_dense, aux_d = moe_dense_dispatch(cfg, p, x)
+y_dense, aux_d, _ = moe_held(cfg, p, x)
 err = np.abs(np.asarray(y_ep) - np.asarray(y_dense)).max()
 scale = np.abs(np.asarray(y_dense)).max()
 assert err < 1e-3 * scale + 1e-4, (err, scale)
@@ -112,14 +70,13 @@ def test_router_gates_normalized():
 
 
 def test_ep_padded_experts_matches_dense():
-    """EP with a 40->48 padded expert table == dense oracle on 40 experts
+    """EP with a 40->48 padded expert table == held path on 40 experts
     (granite hillclimb path; dead experts must contribute nothing)."""
     code = """
 import dataclasses, jax, numpy as np, jax.numpy as jnp
 from repro.configs import get_config, reduced
 from repro.models.layers import init_params
-from repro.models.moe import (moe_dense_dispatch, moe_ep_shard_map,
-                              moe_template)
+from repro.models.moe import moe_ep_shard_map, moe_held, moe_template
 import repro.models.moe as moe_mod
 from repro.models.sharding import MeshCtx
 from repro.launch.mesh import make_mesh
@@ -136,7 +93,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model))
 moe_mod.EP_CHUNK_TOKENS = 8
 with mesh:
     y_ep, _ = jax.jit(lambda xx: moe_ep_shard_map(cfg, p, xx, ctx))(x)
-y_dense, _ = moe_dense_dispatch(cfg, p, x)
+y_dense, _, _ = moe_held(cfg, p, x)
 err = np.abs(np.asarray(y_ep) - np.asarray(y_dense)).max()
 scale = np.abs(np.asarray(y_dense)).max()
 assert err < 1e-3 * scale + 1e-4, (err, scale)

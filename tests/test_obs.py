@@ -20,7 +20,7 @@ from repro import obs  # noqa: E402
 from repro.launch.serve import summary  # noqa: E402
 from repro.serving import faults  # noqa: E402
 from repro.serving.engine import (Request, ServingEngine,  # noqa: E402
-                                  decode_lowering)
+                                  _shared_prefill, decode_lowering)
 
 
 @pytest.fixture(autouse=True)
@@ -269,6 +269,9 @@ def test_serving_programs_lower_under_their_names():
     assert "jit_serve_decode" in text and "jit_impl" not in text
     for scope in ("attn/", "mlp/", "head/", "sample/"):
         assert scope in text, scope
+    # the prefill program and its seen lengths are shared process-wide by
+    # config: start from none, whatever ran earlier in this process
+    _shared_prefill.cache_clear()
     eng = ServingEngine(cfg, faults.fixture()[1], slots=2, max_seq=32)
     eng._prefill_one(jnp.zeros((1, 4), jnp.int32), 4)
     fn, seen = eng._prefill

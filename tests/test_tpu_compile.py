@@ -9,10 +9,14 @@ check results). They guard the main path at real widths:
 
 - the flash-attention kernel, forward and forward+backward, at the
   blocks it picks for itself (1024 at the train cell's 4096 x 128
-  heads: the tiles must fit the default scoped VMEM);
+  heads: the tiles must fit the default scoped VMEM), and with the
+  Mellum2 cell's 1024 window at 8192;
 - the bf16 and int8 Pallas matmuls;
 - the full-width stablelm-1.6b serving decode step at the slot pool
-  ``chip_smoke.py`` uses, and its training step at the depth cut it uses.
+  ``chip_smoke.py`` uses, and its training step at the depth cut it uses;
+- MoE train and decode steps over a 2 x 2 mesh of four chips: the
+  grouped products are Pallas calls XLA cannot partition, so the held
+  expert layer must run as a shard_map there.
 
 The topology is described inside a module fixture (never at import), so
 every xdist worker collects the same tests and only the one that runs
@@ -37,7 +41,8 @@ KERNEL = "tpu_custom_call"
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The described four-chip v5e host (2 x 2)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -51,9 +56,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _sds(shape, dtype, sharding):
@@ -101,6 +111,173 @@ def test_flash_attention_backward_compiles(one_chip, case):
     text = grad.lower(q, q, q, kv_valid).compile().as_text()
     # forward (lse residual) + the dQ and dK/dV kernels
     assert text.count(KERNEL) >= 3
+
+
+@pytest.mark.parametrize("pass_", ["fwd", "bwd"])
+def test_flash_attention_window_compiles(one_chip, pass_):
+    """The Mellum2 cell's sliding layers: q (2, 32, 8192, 128) bf16 with
+    a 1024 window, forward alone and with both backward kernels."""
+    from repro.kernels.attention import flash_attention
+    q = _sds((2, 32, 8192, 128), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=1024,
+                               interpret=False)
+    fn = fwd if pass_ == "fwd" else jax.grad(
+        lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    text = jax.jit(fn).lower(q, q, q).compile().as_text()
+    assert text.count(KERNEL) >= (1 if pass_ == "fwd" else 3)
+
+
+def test_flash_window_none_is_the_causal_program(one_chip):
+    """``window=None`` lowers to the very program of a call that names no
+    window; a window changes the kernels' bodies, not their outputs."""
+    import re
+
+    from repro.kernels.attention import flash_attention
+    q = _sds((1, 8, 2048, 128), jnp.bfloat16, one_chip)
+
+    def step(**kw):
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=False,
+                                   **kw).astype(jnp.float32).sum()
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q)
+
+    def outputs(lowered):
+        return sorted(re.sub(r"\{[^}]*\}", "", ln.split(" = ", 1)[1]
+                             .split(" custom-call(")[0])
+                      for ln in lowered.compile().as_text().splitlines()
+                      if KERNEL in ln and " custom-call(" in ln)
+
+    plain, none, band = step(), step(window=None), step(window=512)
+    assert none.as_text() == plain.as_text()
+    assert band.as_text() != plain.as_text()
+    assert len(outputs(plain)) == 3
+    assert outputs(band) == outputs(plain)
+
+
+def test_moe_window_step_kernels_keep_their_names(one_chip, monkeypatch):
+    """A Mellum2-shaped train step (one period of 3 sliding + 1 full
+    layer, 8 of 64 experts held, narrower widths) names its grouped
+    products ``gmm``/``tgmm`` and its flash kernels as the cell's
+    ``expert_kernels`` and ``flash_kernels`` patterns find them: per
+    layer 3 forward products, 3 more under remat and 3 input gradients
+    (``gmm``), 3 weight gradients (``tgmm``); the attention scopes name
+    the layer kinds."""
+    import json
+    import re
+
+    from repro.configs.base import ArchConfig
+    from repro.kernels import ops
+    from repro.models import attention
+    from repro.models.sharding import MeshCtx
+    from repro.optim.adamw import OptConfig
+    from repro.train import step as step_lib
+    monkeypatch.setattr(attention, "flash_route_enabled",
+                        lambda mode="auto": True)
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    with open(os.path.join(REPO, "bench", "configs",
+                           "mellum2-12b-l4-ep8.json")) as f:
+        arch = json.load(f)["arch"]
+    with open(os.path.join(REPO, "bench", "workloads",
+                           "mellum2-12b.train-8k.json")) as f:
+        cell = json.load(f)
+    cfg = dataclasses.replace(ArchConfig(**arch), d_model=512,
+                              vocab_size=1024)
+    bundle = step_lib.make_train_step(cfg, OptConfig(), MeshCtx(mesh=None))
+    state = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), bundle.abstract_state)
+    tok = _sds((1, 2048), jnp.int32, one_chip)
+    lowered = jax.jit(bundle.step_fn).lower(state,
+                                            {"tokens": tok, "labels": tok})
+    scoped = lowered.as_text(debug_info=True)
+    assert "attn.window/" in scoped and "attn.full/" in scoped
+    assert "moe.experts/" in scoped
+    lines = [ln.strip() for ln in lowered.compile().as_text().splitlines()]
+    patterns = dict(cell["flash_kernels"], **cell["expert_kernels"])
+    found = {k: sum(1 for ln in lines if re.search(p, ln))
+             for k, p in patterns.items()}
+    assert found == {"fwd": 8, "dq": 4, "dkv": 4, "gmm": 36, "tgmm": 12}, \
+        found
+
+
+def _moe_mesh_cfg(name):
+    """A Mellum2-shaped config (held experts of a wider router, split
+    over the lanes) at narrow widths, or reduced granite-moe (padded
+    experts, the lanes split each expert's hidden width)."""
+    import json
+
+    from repro.configs import get_config, reduced
+    from repro.configs.base import ArchConfig
+    if name == "granite":
+        return reduced(get_config("granite-moe-3b-a800m"))
+    with open(os.path.join(REPO, "bench", "configs",
+                           "mellum2-12b-l4-ep8.json")) as f:
+        arch = json.load(f)["arch"]
+    return dataclasses.replace(ArchConfig(**arch), d_model=512,
+                               vocab_size=1024)
+
+
+@pytest.mark.parametrize("step", ["train", "decode"])
+@pytest.mark.parametrize("name", ["mellum2", "granite"])
+def test_moe_steps_compile_on_a_four_chip_mesh(v5e_2x2, monkeypatch, name,
+                                               step):
+    """An MoE train step and decode step over a 2 x 2 mesh (data x model)
+    of described v5e chips compile with the grouped products as Mosaic
+    calls: XLA refuses to partition one outside a shard_map."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.kernels import ops
+    from repro.models import attention
+    from repro.models import transformer as tf
+    from repro.models.layers import abstract_params
+    from repro.models.sharding import MeshCtx
+    from repro.optim.adamw import OptConfig
+    from repro.train import step as step_lib
+    monkeypatch.setattr(attention, "flash_route_enabled",
+                        lambda mode="auto": True)
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = _moe_mesh_cfg(name)
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(2, 2), ("data", "model"))
+    ctx = MeshCtx(mesh=mesh)
+    pspecs = step_lib.train_state_specs(cfg, ctx)["params"]
+    if step == "train":
+        bundle = step_lib.make_train_step(cfg, OptConfig(), ctx)
+        tok = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+        batch = {"tokens": tok, "labels": tok}
+        state_sh = step_lib.named_for(bundle.state_specs,
+                                      bundle.abstract_state, mesh)
+        with mesh:
+            lowered = jax.jit(
+                bundle.step_fn,
+                in_shardings=(state_sh, step_lib.named_for(
+                    bundle.batch_specs, batch, mesh)),
+                out_shardings=(state_sh, None),
+            ).lower(bundle.abstract_state, batch)
+    else:
+        aparams = abstract_params(tf.model_template(cfg),
+                                  jnp.dtype(cfg.param_dtype))
+        acache = tf.init_cache(cfg, 4, 256, abstract=True)
+        batch = {"tokens": jax.ShapeDtypeStruct((4, 1), jnp.int32)}
+        cspecs = step_lib.named_for(step_lib.cache_pspecs(cfg, ctx),
+                                    acache, mesh)
+        with mesh:
+            lowered = jax.jit(
+                step_lib.make_decode_step(cfg, ctx),
+                in_shardings=(
+                    step_lib.named_for(pspecs, aparams, mesh), cspecs,
+                    step_lib.named_for(
+                        step_lib.batch_pspecs(cfg, "decode", ctx), batch,
+                        mesh)),
+                out_shardings=(None, cspecs),
+            ).lower(aparams, acache, batch)
+    text = lowered.compile().as_text()
+    # three grouped products a layer forward, and their gradients
+    per_layer = 3 if step == "decode" else 9
+    assert text.count(KERNEL) >= per_layer * cfg.n_layers, \
+        text.count(KERNEL)
 
 
 @pytest.mark.parametrize("kind", ["bf16", "int8"])
